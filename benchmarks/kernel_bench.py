@@ -474,5 +474,8 @@ def run() -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(r)

@@ -48,9 +48,11 @@ class QuantizedModel:
     paths: list[str]                 # leaf order for reconstruction
     stacked: dict[str, bool]         # path -> had leading layer axis
     policy: QuantPolicy
+    dtypes: dict[str, Any]           # path -> dtype of the original leaf
 
     def materialize(self) -> Any:
-        """Params pytree with effective (dequantized) weights."""
+        """Params pytree with effective (dequantized) weights, each in the
+        dtype of the leaf it replaces (a bf16 model stays bf16)."""
         leaves = []
         for p in self.paths:
             if p in self.qleaves:
@@ -59,7 +61,7 @@ class QuantizedModel:
                     w = jax.vmap(lambda t: t.dequantize())(qt)
                 else:
                     w = qt.dequantize()
-                leaves.append(w)
+                leaves.append(w.astype(self.dtypes[p]))
             else:
                 leaves.append(self.passthrough[p])
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
@@ -120,6 +122,7 @@ def restructure(
     passthrough: dict[str, jax.Array] = {}
     paths: list[str] = []
     stacked: dict[str, bool] = {}
+    dtypes: dict[str, Any] = {}
 
     for path, leaf in flat:
         p = _path_str(path)
@@ -132,12 +135,13 @@ def restructure(
                 is_stacked = leaf.ndim >= 3 and "layers" in p.lower()
             qleaves[p] = _transform_leaf(leaf, policy, is_stacked)
             stacked[p] = is_stacked
+            dtypes[p] = leaf.dtype
         else:
             passthrough[p] = leaf
             stacked[p] = False
     return QuantizedModel(
         qleaves=qleaves, passthrough=passthrough, treedef=treedef,
-        paths=paths, stacked=stacked, policy=policy,
+        paths=paths, stacked=stacked, policy=policy, dtypes=dtypes,
     )
 
 

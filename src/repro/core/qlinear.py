@@ -42,8 +42,8 @@ def splitq_linear_3pass(
     """Paper-faithful: k separate (de)quantized layers, outputs summed."""
     y = jnp.zeros(x.shape[:-1] + (sq.shape[-1],), jnp.float32)
     for c in range(sq.k):
-        q = unpack_codes(sq.planes[c], sq.bits, out_len=sq.shape[-1])
-        wc = dequantize(q.reshape(sq.shape), sq.plane_qparams(c))
+        q = unpack_codes(sq.planes[c], sq.bits, out_len=sq.shape[-2])
+        wc = dequantize(q, sq.plane_qparams(c))
         y = y + jnp.dot(x.astype(jnp.float32), wc)
     if b is not None:
         y = y + b

@@ -15,7 +15,8 @@ scheme matches advanced GPU-hungry algorithms. We implement:
 * ``include_zero`` range extension — required by the split transform so that
   masked-out weights encode exactly to the zero-point (see core/split.py),
 * int4/int2 bit-packing into int8 carriers for real deployment storage
-  (kernels unpack in VMEM).
+  (kernels unpack in VMEM); codes pack along the contraction axis (-2), so
+  quantized tensors are at least 2-D.
 
 All ops are pure jnp and jit-safe; scalars stay in fp32.
 """
@@ -128,46 +129,72 @@ def fake_quant(
 
 
 # ---------------------------------------------------------------------------
-# Bit packing (int4 / int2 codes into int8 carriers, little-nibble-first).
+# Bit packing (int4 / int2 codes into int8 carriers, slab-striped rows).
+#
+# Codes pack along the CONTRACTION axis (-2; rows of a (K, N) weight) so the
+# output axis N stays unpacked: a kernel tile of any lane-aligned width, a
+# tensor-parallel shard of N, and the N-concatenation of grouped projections
+# are all plain slices of the packed array. Rows go in slabs of
+# ``PACK_SLAB``; within a slab of S rows, byte row r holds, in its i-th
+# b-bit field (little end first), logical row i*(S/per) + r. Unpacking a
+# slab is therefore ``per`` shifts of the whole carrier tile stacked along
+# rows — whole (8, 128) tiles on a TPU, no lane or sublane interleave.
 # ---------------------------------------------------------------------------
+
+PACK_SLAB = 128
+
+
+def packed_rows(n: int) -> int:
+    """Logical rows a packed tensor of ``n`` rows stores.
+
+    Padded to a multiple of 4 (every carrier byte full for 2- and 4-bit
+    codes) and, past one slab, to whole slabs: the slab a row lands in,
+    and so the layout, never depends on how a kernel tiles the rows."""
+    n4 = -(-max(n, 1) // 4) * 4
+    return n4 if n4 <= PACK_SLAB else -(-n // PACK_SLAB) * PACK_SLAB
 
 
 def pack_codes(q: jax.Array, bits: int) -> jax.Array:
-    """Pack signed b-bit codes (stored in int8) along the last axis.
+    """Pack signed b-bit codes (stored in int8) along axis -2.
 
-    bits=8 is the identity. bits=4 packs 2/byte, bits=2 packs 4/byte.
-    The last axis must be divisible by (8 // bits).
+    Rows are zero-padded to :func:`packed_rows`; bits=8 stores them as is,
+    bits=4 packs 2 rows per byte, bits=2 packs 4.
     """
+    n = q.shape[-2]
+    rows = packed_rows(n)
+    if rows != n:
+        q = jnp.pad(q, [(0, 0)] * (q.ndim - 2) + [(0, rows - n), (0, 0)])
     if bits == 8:
-        return q
+        return q.astype(INT_DTYPE)
     per = 8 // bits
-    assert q.shape[-1] % per == 0, (q.shape, bits)
-    u = (q.astype(jnp.int32) & ((1 << bits) - 1)).astype(jnp.uint8)
-    u = u.reshape(q.shape[:-1] + (q.shape[-1] // per, per))
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits).astype(jnp.uint8)
-    packed = jnp.zeros(u.shape[:-1], jnp.uint8)
-    for i in range(per):
-        packed = packed | (u[..., i] << shifts[i])
-    return packed.astype(jnp.int8)
+    slab = min(PACK_SLAB, rows)
+    lead, cols = q.shape[:-2], q.shape[-1]
+    u = (q.astype(jnp.int32) & ((1 << bits) - 1)).reshape(
+        lead + (rows // slab, per, slab // per, cols))
+    packed = u[..., 0, :, :]
+    for i in range(1, per):
+        packed = packed | (u[..., i, :, :] << (i * bits))
+    packed = jnp.where(packed >= 128, packed - 256, packed)
+    return packed.reshape(lead + (rows // per, cols)).astype(INT_DTYPE)
 
 
 def unpack_codes(p: jax.Array, bits: int, out_len: int | None = None) -> jax.Array:
-    """Inverse of :func:`pack_codes`; returns sign-extended int8 codes."""
-    if bits == 8:
-        return p
-    per = 8 // bits
-    u = p.astype(jnp.uint8)
-    parts = []
-    mask = (1 << bits) - 1
-    for i in range(per):
-        v = (u >> jnp.uint8(i * bits)) & jnp.uint8(mask)
-        # sign extend from `bits`
-        v = v.astype(jnp.int32)
-        v = jnp.where(v >= (1 << (bits - 1)), v - (1 << bits), v)
-        parts.append(v.astype(jnp.int8))
-    out = jnp.stack(parts, axis=-1).reshape(p.shape[:-1] + (p.shape[-1] * per,))
+    """Inverse of :func:`pack_codes`: sign-extended int8 codes, rows cut to
+    ``out_len`` when given."""
+    out = p
+    if bits != 8:
+        per = 8 // bits
+        rows = p.shape[-2] * per
+        slab = min(PACK_SLAB, rows)
+        lead, cols = p.shape[:-2], p.shape[-1]
+        x = p.astype(jnp.int32).reshape(
+            lead + (rows // slab, 1, slab // per, cols))
+        left = (32 - bits - bits * jnp.arange(per, dtype=jnp.int32))
+        # field i to the top of the word, arithmetic shift back: sign-extends
+        v = (x << left[:, None, None]) >> (32 - bits)
+        out = v.reshape(lead + (rows, cols)).astype(INT_DTYPE)
     if out_len is not None:
-        out = out[..., :out_len]
+        out = out[..., :out_len, :]
     return out
 
 
@@ -190,8 +217,8 @@ class QTensor:
     shape: tuple[int, ...]
 
     def dequantize(self) -> jax.Array:
-        q = unpack_codes(self.packed, self.qp.bits, out_len=self.shape[-1])
-        return dequantize(q.reshape(self.shape), self.qp)
+        q = unpack_codes(self.packed, self.qp.bits, out_len=self.shape[-2])
+        return dequantize(q, self.qp)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "symmetric", "include_zero"))
@@ -200,8 +227,5 @@ def quantize_tensor(
 ) -> QTensor:
     """Per-tensor quantize + pack (the paper's deployment storage format)."""
     qp = compute_qparams(x, bits, symmetric=symmetric, include_zero=include_zero)
-    q = quantize(x, qp)
-    pad = (-x.shape[-1]) % (8 // bits)
-    if pad:
-        q = jnp.pad(q, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-    return QTensor(packed=pack_codes(q, bits), qp=qp, shape=x.shape)
+    return QTensor(packed=pack_codes(quantize(x, qp), bits), qp=qp,
+                   shape=x.shape)

@@ -82,8 +82,8 @@ class SplitQTensor:
         """Effective weight Ŵ = Σ_c dequant(plane_c)."""
         out = jnp.zeros(self.shape, jnp.float32)
         for c in range(self.k):
-            q = unpack_codes(self.planes[c], self.bits, out_len=self.shape[-1])
-            out = out + dequantize(q.reshape(self.shape), self.plane_qparams(c))
+            q = unpack_codes(self.planes[c], self.bits, out_len=self.shape[-2])
+            out = out + dequantize(q, self.plane_qparams(c))
         return out
 
 
@@ -104,10 +104,10 @@ class PackedSplitQTensor:
     shape: tuple[int, ...]
 
     def dequantize(self) -> jax.Array:
-        q = unpack_codes(self.codes, self.bits, out_len=self.shape[-1])
-        q = q.reshape(self.shape).astype(jnp.float32)
-        cid = unpack_codes(self.cids, 2, out_len=self.shape[-1])
-        cid = (cid.reshape(self.shape).astype(jnp.int32)) & 0x3
+        q = unpack_codes(self.codes, self.bits, out_len=self.shape[-2])
+        q = q.astype(jnp.float32)
+        cid = unpack_codes(self.cids, 2, out_len=self.shape[-2])
+        cid = cid.astype(jnp.int32) & 0x3
         s = self.scales[cid]
         z = self.zeros[cid]
         return (q - z) / s
@@ -116,7 +116,7 @@ class PackedSplitQTensor:
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["codes", "cids", "scales", "zeros"],
-    meta_fields=["bits", "kclusters", "widths", "align"],
+    meta_fields=["bits", "kclusters", "rows", "widths", "align"],
 )
 @dataclasses.dataclass(frozen=True)
 class PackedSplitQGroup:
@@ -131,12 +131,13 @@ class PackedSplitQGroup:
     cluster ids at 2 bits — the 6-bit/weight footprint survives grouping.
     """
 
-    codes: jax.Array   # (..., K, Np_tot//per) int8 carriers
-    cids: jax.Array    # (..., K, Np_tot//4) packed 2-bit ids
+    codes: jax.Array   # (..., Kp//per, Np_tot) int8 carriers
+    cids: jax.Array    # (..., Kp//4, Np_tot) packed 2-bit ids
     scales: jax.Array  # (..., G*k) fp32, member-major
     zeros: jax.Array   # (..., G*k) fp32
     bits: int
     kclusters: int
+    rows: int                 # logical K shared by every member
     widths: tuple[int, ...]   # logical N of each member
     align: int                # member padding granularity along N
 
@@ -149,11 +150,10 @@ class PackedSplitQGroup:
 
     def dequantize(self) -> list[jax.Array]:
         """Per-member effective weights (the padding columns are dropped)."""
-        n_tot = sum(self.padded_widths())
-        q = unpack_codes(self.codes, self.bits, out_len=n_tot)
-        q = q.reshape(self.codes.shape[:-1] + (n_tot,)).astype(jnp.float32)
-        cid = unpack_codes(self.cids, 2, out_len=n_tot)
-        cid = cid.reshape(q.shape).astype(jnp.int32) & 0x3
+        q = unpack_codes(self.codes, self.bits, out_len=self.rows)
+        q = q.astype(jnp.float32)
+        cid = unpack_codes(self.cids, 2, out_len=self.rows)
+        cid = cid.astype(jnp.int32) & 0x3
         out, off = [], 0
         for g, (w, pw) in enumerate(zip(self.widths, self.padded_widths())):
             qs = q[..., off:off + w]
@@ -183,26 +183,26 @@ def group_packed(
     output columns are garbage and sliced off by the kernel wrapper).
     """
     bits = members[0].bits
-    per = 8 // bits
     k = members[0].scales.shape[-1]
-    assert all(m.bits == bits and m.scales.shape[-1] == k for m in members)
+    rows = members[0].shape[-2]
+    assert all(m.bits == bits and m.scales.shape[-1] == k
+               and m.shape[-2] == rows for m in members)
     widths = tuple(m.shape[-1] for m in members)
     if align is None:
         align = 512 if all(w % 512 == 0 for w in widths) else 128
-    codes, cids = [], []
-    for m, w in zip(members, widths):
-        pw = -(-w // align) * align
-        pad_codes = (pw - m.codes.shape[-1] * per) // per
-        pad_cids = pw // 4 - m.cids.shape[-1]
-        lead = [(0, 0)] * (m.codes.ndim - 1)
-        codes.append(jnp.pad(m.codes, lead + [(0, pad_codes)]))
-        cids.append(jnp.pad(m.cids, lead + [(0, pad_cids)]))
+    lead = [(0, 0)] * (members[0].codes.ndim - 1)
+
+    def padded(a, w):
+        return jnp.pad(a, lead + [(0, -(-w // align) * align - w)])
+
     return PackedSplitQGroup(
-        codes=jnp.concatenate(codes, axis=-1),
-        cids=jnp.concatenate(cids, axis=-1),
+        codes=jnp.concatenate(
+            [padded(m.codes, w) for m, w in zip(members, widths)], axis=-1),
+        cids=jnp.concatenate(
+            [padded(m.cids, w) for m, w in zip(members, widths)], axis=-1),
         scales=jnp.concatenate([m.scales for m in members], axis=-1),
         zeros=jnp.concatenate([m.zeros for m in members], axis=-1),
-        bits=bits, kclusters=k, widths=widths, align=align,
+        bits=bits, kclusters=k, rows=rows, widths=widths, align=align,
     )
 
 
@@ -237,13 +237,6 @@ def plane_qparams_from_ids(
     return jnp.stack(scales), jnp.stack(zeros)
 
 
-def _pad_last(x: jax.Array, mult: int) -> jax.Array:
-    pad = (-x.shape[-1]) % mult
-    if pad:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-    return x
-
-
 @functools.partial(jax.jit, static_argnames=("bits", "k", "bins", "iters"))
 def split_quantize(
     w: jax.Array,
@@ -259,7 +252,7 @@ def split_quantize(
     for c in range(k):
         qp = QParams(scales[c], zeros[c], bits)
         wc = jnp.where(ids == c, w.astype(jnp.float32), 0.0)
-        planes.append(pack_codes(_pad_last(quantize(wc, qp), 8 // bits), bits))
+        planes.append(pack_codes(quantize(wc, qp), bits))
     return SplitQTensor(
         planes=jnp.stack(planes), scales=scales, zeros=zeros, bits=bits,
         shape=tuple(w.shape), info=info,
@@ -287,8 +280,8 @@ def split_quantize_packed(
     z = zeros[ids]
     q = jnp.round(s * w.astype(jnp.float32)) + z
     q = jnp.clip(q, -(2 ** (bits - 1)), 2 ** (bits - 1) - 1).astype(jnp.int8)
-    codes = pack_codes(_pad_last(q, 8 // bits), bits)
-    cids = pack_codes(_pad_last(ids.astype(jnp.int8), 4), 2)
+    codes = pack_codes(q, bits)
+    cids = pack_codes(ids.astype(jnp.int8), 2)
     return PackedSplitQTensor(
         codes=codes, cids=cids, scales=scales, zeros=zeros, bits=bits,
         shape=tuple(w.shape),

@@ -7,21 +7,23 @@ Two layers, cheapest first:
    big bk to amortize grid overhead; bn capped by a VMEM budget for the
    fp32 accumulator + unpacked weight tile).
 2. **Measured cache**: an optional JSON file (``SPLITQ_TUNE_CACHE`` env var
-   or an explicit path) mapping ``"MxKxN@bits/dS"`` -> ``[bm, bn, bk]``.
+   or an explicit path) mapping ``"MxKxN@bits"`` -> ``[bm, bn, bk]``.
    ``autotune()`` times the candidate blocks for a concrete call and records
    the winner, so serving picks measured shapes on the next run — levanter-
    style config plumbing: the cache is plain data, reviewable and shippable.
 
-Keys carry the tensor-parallel shard count (``/dS``): a TP shard runs the
-*per-shard* matmul (N/S output columns per device), and a block tuned for
-the full weight is the wrong answer for the shard. M/K/N in the key are the
-per-shard shape; entries in the old global-shape format (no ``/dS`` suffix)
-are stale by construction and dropped at load time.
+M/K/N in a key are the matmul one device runs; on a serving mesh that is
+the whole weight (kernels gather it, see ``repro.kernels.ops``). Entries of
+the schema-2 format (keys ending in a ``/dS`` shard count) were tuned for
+the column-packed layout and are dropped at load time; every hit is checked
+against the kernel contracts below before it is used.
 
 All outputs satisfy the kernel contracts: bm % 8 == 0 (fp32 sublane; 16 for
-bf16 activations), bn % 128 == 0 (lane), bk % 128 == 0, and for grouped
-launches bn divides the group's N alignment so every output block belongs
-to exactly one member.
+bf16 activations), bn % 128 == 0 (lane), bk covers whole packing slabs
+(a multiple of 128, or the whole K of a single-slab tensor — see
+``repro.core.quantize.pack_codes``), and for grouped launches bn divides
+the group's N alignment so every output block belongs to exactly one
+member.
 """
 from __future__ import annotations
 
@@ -32,12 +34,14 @@ import pathlib
 import re
 from typing import Callable, Iterable
 
+from repro.core.quantize import PACK_SLAB, packed_rows
+
 ENV_CACHE = "SPLITQ_TUNE_CACHE"
 
-# VMEM working-set budget per kernel instance (acc fp32 + x tile + unpacked
-# weight tile + double-buffered packed tiles). Conservative vs the ~16 MB
-# physical VMEM so the pipeline has headroom for double buffering.
-VMEM_BUDGET = 8 * 1024 * 1024
+# Scoped-VMEM budget per kernel instance. Mosaic's default scoped limit on
+# a v5e is 16 MiB (its out-of-VMEM error names the limit); a quarter stays
+# free for the compiler's own scratch.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 BN_CANDIDATES = (512, 256, 128)
 BK_CANDIDATES = (512, 256, 128)
@@ -58,11 +62,13 @@ def _round_up(v: int, mult: int) -> int:
 
 
 def _vmem_bytes(bm: int, bn: int, bk: int, bits: int) -> int:
-    acc = bm * bn * 4
-    x_tile = bm * bk * 4
-    w_unpacked = bk * bn * 4
-    w_packed = 2 * (bk * bn * (bits + 2) // 8)  # double-buffered stream
-    return acc + x_tile + w_unpacked + w_packed
+    """Scoped VMEM the packed kernel allocates for one block shape: the
+    double-buffered x, code, cluster-id and output tiles (activations
+    counted as fp32, the worst case) plus the fp32 accumulator. This is
+    the sum Mosaic reports when it runs out; the unpacked weight tile is
+    not in it (the compiler streams it through vregs and its own scratch)."""
+    tiles = bm * bk * 4 + bk * bn * (bits + 2) // 8 + bm * bn * 4
+    return 2 * tiles + bm * bn * 4
 
 
 def heuristic_block(
@@ -75,13 +81,22 @@ def heuristic_block(
     bn = next((c for c in BN_CANDIDATES if n >= c), 128)
     if max_bn is not None:
         bn = min(bn, max_bn)
-    bk = next((c for c in BK_CANDIDATES if k >= 4 * c), 128)
-    bk = min(bk, _round_up(max(k, 1), 128))
+    kp = packed_rows(k)
+    if kp < PACK_SLAB:
+        bk = kp  # a single partial slab: the tile is the whole K
+    else:
+        bk = min(next((c for c in BK_CANDIDATES if k >= 4 * c), 128), kp)
     while _vmem_bytes(bm, bn, bk, bits) > VMEM_BUDGET and bn > 128:
         bn //= 2
     while _vmem_bytes(bm, bn, bk, bits) > VMEM_BUDGET and bk > 128:
         bk //= 2
     return (bm, bn, bk)
+
+
+def _bk_valid(bk: int, k: int) -> bool:
+    """bk tiles whole packing slabs of a K-row weight."""
+    kp = packed_rows(k)
+    return bk == kp if kp < PACK_SLAB else bk % 128 == 0
 
 
 def candidate_blocks(
@@ -92,10 +107,12 @@ def candidate_blocks(
     base = heuristic_block(m, k, n, bits, max_bn=max_bn, bf16_acts=bf16_acts)
     out = {base}
     bm = base[0]
+    # a single-slab K has one valid bk, the heuristic's
+    bks = BK_CANDIDATES if packed_rows(k) >= PACK_SLAB else ()
     for bn in BN_CANDIDATES:
         if max_bn is not None and bn > max_bn:
             continue
-        for bk in BK_CANDIDATES:
+        for bk in bks:
             if _vmem_bytes(bm, bn, bk, bits) <= VMEM_BUDGET:
                 out.add((bm, bn, bk))
     return sorted(out)
@@ -106,17 +123,14 @@ def candidate_blocks(
 # ---------------------------------------------------------------------------
 
 
-def cache_key(m: int, k: int, n: int, bits: int, bf16_acts: bool = False,
-              n_shards: int = 1) -> str:
+def cache_key(m: int, k: int, n: int, bits: int,
+              bf16_acts: bool = False) -> str:
     # activation dtype changes both the sublane constraint and the measured
-    # winner, so bf16 entries get their own namespace; n_shards is the TP
-    # degree the (m, k, n) PER-SHARD shape was tuned under — a shard must
-    # never reuse a block tuned for the global weight (and vice versa)
-    return (f"{m}x{k}x{n}@{bits}" + ("+bf16" if bf16_acts else "")
-            + f"/d{n_shards}")
+    # winner, so bf16 entries get their own namespace
+    return f"{m}x{k}x{n}@{bits}" + ("+bf16" if bf16_acts else "")
 
 
-_KEY_RE = re.compile(r"^\d+x\d+x\d+@\d+(\+bf16)?/d\d+$")
+_KEY_RE = re.compile(r"^\d+x\d+x\d+@\d+(\+bf16)?$")
 
 
 def _valid_block_entry(v) -> bool:
@@ -139,9 +153,8 @@ class TuneCache:
                 # validate per entry at LOAD time: a hand-edited 2-element
                 # (or non-int) entry must degrade to the heuristic here,
                 # not raise inside choose_block on the serving hot path.
-                # Keys missing the /dS shard suffix are schema-1 entries
-                # tuned on GLOBAL shapes — stale for any sharded run and
-                # ambiguous for unsharded ones, so they are dropped too.
+                # Schema-2 keys (a /dS shard suffix) were tuned for the
+                # column-packed layout, so they are dropped too.
                 self.table = {k: tuple(v)
                               for k, v in raw.get("blocks", raw).items()
                               if _valid_block_entry(v)
@@ -151,14 +164,12 @@ class TuneCache:
                 # heuristics cover every shape
                 self.table = {}
 
-    def get(self, m: int, k: int, n: int, bits: int, bf16_acts: bool = False,
-            n_shards: int = 1):
-        return self.table.get(cache_key(m, k, n, bits, bf16_acts, n_shards))
+    def get(self, m: int, k: int, n: int, bits: int, bf16_acts: bool = False):
+        return self.table.get(cache_key(m, k, n, bits, bf16_acts))
 
     def put(self, m: int, k: int, n: int, bits: int,
-            block: tuple[int, int, int], bf16_acts: bool = False,
-            n_shards: int = 1):
-        self.table[cache_key(m, k, n, bits, bf16_acts, n_shards)] = tuple(block)
+            block: tuple[int, int, int], bf16_acts: bool = False):
+        self.table[cache_key(m, k, n, bits, bf16_acts)] = tuple(block)
 
     def save(self, path: str | os.PathLike | None = None):
         p = pathlib.Path(path) if path else self.path
@@ -166,7 +177,7 @@ class TuneCache:
             raise ValueError("no cache path configured")
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(json.dumps(
-            {"schema": 2, "blocks": {k: list(v) for k, v in
+            {"schema": 3, "blocks": {k: list(v) for k, v in
                                      sorted(self.table.items())}},
             indent=2,
         ))
@@ -189,17 +200,14 @@ def reset_cache():
 
 def choose_block(
     m: int, k: int, n: int, bits: int, *, max_bn: int | None = None,
-    bf16_acts: bool = False, n_shards: int = 1,
+    bf16_acts: bool = False,
 ) -> tuple[int, int, int]:
-    """Dispatch: measured cache hit if valid for this call, else heuristic.
-
-    ``(m, k, n)`` is the PER-SHARD shape when ``n_shards > 1`` — callers
-    running under tensor parallelism divide their output width first."""
-    hit = get_cache().get(m, k, n, bits, bf16_acts, n_shards)
+    """Dispatch: measured cache hit if valid for this call, else heuristic."""
+    hit = get_cache().get(m, k, n, bits, bf16_acts)
     if hit is not None and _valid_block_entry(hit):
         bm, bn, bk = hit
         sublane = 16 if bf16_acts else 8
-        ok = bm % sublane == 0 and bn % 128 == 0 and bk % 128 == 0
+        ok = bm % sublane == 0 and bn % 128 == 0 and _bk_valid(bk, k)
         # re-check the VMEM budget on every hit: an entry tuned on another
         # machine (or hand-edited) may exceed this build's working set
         ok = ok and _vmem_bytes(bm, bn, bk, bits) <= VMEM_BUDGET
@@ -228,7 +236,6 @@ def autotune(
     m: int, k: int, n: int, bits: int,
     *, candidates: Iterable[tuple[int, int, int]] | None = None,
     iters: int = 3, max_bn: int | None = None, bf16_acts: bool = False,
-    n_shards: int = 1,
 ) -> tuple[tuple[int, int, int], dict[str, float]]:
     """Time ``run(block)`` over the candidate set; record the winner.
 
@@ -259,8 +266,8 @@ def autotune(
         # tuning outcome — don't record an untimed "winner" silently.
         raise RuntimeError(
             f"autotune: all {len(cands)} candidate blocks failed for "
-            f"{cache_key(m, k, n, bits, bf16_acts, n_shards)}"
+            f"{cache_key(m, k, n, bits, bf16_acts)}"
         ) from last_err
-    get_cache().put(m, k, n, bits, best, bf16_acts, n_shards)
+    get_cache().put(m, k, n, bits, best, bf16_acts)
     _count("autotune_winners_total", "measured winners recorded")
     return best, timings

@@ -1,12 +1,18 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles: padding to block multiples (zero-padding K on the activation side
-is value-preserving; N/M padding is sliced off), backend dispatch (compiled
-Pallas on TPU, ``interpret=True`` elsewhere — this container is CPU, so
-tests exercise the interpreter path), block-shape dispatch via the engine
+is value-preserving; N/M padding is sliced off), backend dispatch
+(:func:`interpret_mode`: compiled Pallas on a TPU, the interpreter on the
+CPU, an error anywhere else), block-shape dispatch via the engine
 autotuner when the caller passes ``block=None``, and pytree-level entry
 points taking the core's SplitQTensor / PackedSplitQTensor /
 PackedSplitQGroup containers directly.
+
+On a serving mesh (exact-TP hints) every kernel call runs inside a
+``shard_map``: GSPMD cannot partition a Mosaic kernel. The call's batch
+rows split over the data axes when they divide; its weights, page pools
+and everything else are gathered whole to each device, so each device
+computes exact full-width results for its own rows.
 
 ``count_launches()`` is a tracing-time hook: wrappers bump a counter when a
 quantized kernel is dispatched, so tests can assert launches-per-block of a
@@ -21,14 +27,19 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.core.quantize import PACK_SLAB, packed_rows
 from repro.core.split import PackedSplitQGroup, PackedSplitQTensor, SplitQTensor
+from repro.kernels import paged_attention as paged_mod
 from repro.kernels import ref
 from repro.kernels.kmeans1d import kmeans_assign_reduce_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas
 from repro.kernels.quantize_pack import quantize_pack_pallas
 from repro.kernels.splitq_matmul import splitq_matmul_pallas
 from repro.kernels.splitq_packed import splitq_packed_matmul_pallas
+from repro.runtime.sharding import (dp_axes, mesh_dims, serving_mesh,
+                                    sharding_hints)
 
 DEFAULT_BLOCK = (128, 512, 128)
 
@@ -53,24 +64,54 @@ def _bump(kind: str):
         c["total"] = c.get("total", 0) + 1
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode on this backend.
+
+    Compiled on a TPU, interpreted on the CPU (tests). Any other platform
+    is an error: these are Mosaic TPU kernels, and interpreting them on an
+    accelerator would hide the device behind a slow emulation."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU; "
+        f"the default backend is {platform!r}")
+
+
+def _on_mesh(fn, batched: tuple[int, ...], *args):
+    """``fn(*args)``, inside a shard_map over the serving mesh if any.
+
+    Arguments whose positions are in ``batched`` split their leading
+    (batch) dim over the data axes when it divides; the rest are
+    replicated. The body runs outside the hints, so the wrapper it calls
+    does not open a second shard_map."""
+    mesh = serving_mesh()
+    if mesh is None:
+        return fn(*args)
+    n_dp, _ = mesh_dims(mesh)
+    split = n_dp > 1 and args[0].shape[0] % n_dp == 0
+    rows = P(dp_axes(mesh)) if split else P()
+
+    def body(*a):
+        with sharding_hints(None):
+            return fn(*a)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=tuple(rows if i in batched else P()
+                       for i in range(len(args))),
+        out_specs=rows, check_vma=False,
+    )(*args)
 
 
 def _choose(m, k, n, bits, *, max_bn=None, bf16=False):
     from repro.engine.autotune import choose_block
-    from repro.runtime.sharding import tp_shards
 
-    # Under exact-TP serving hints the weight's output dim is sharded over
-    # `model`: each device runs the PER-SHARD matmul, so the block (and the
-    # tune-cache key) must come from n/tp, not the global width.
-    tp = tp_shards()
-    if tp > 1 and n % tp == 0:
-        n, shards = n // tp, tp
-    else:
-        shards = 1
-    return choose_block(m, k, n, bits, max_bn=max_bn, bf16_acts=bf16,
-                        n_shards=shards)
+    # every device runs the whole (m, k, n) matmul of its rows: on a mesh
+    # the weights are gathered for the call (see _on_mesh)
+    return choose_block(m, k, n, bits, max_bn=max_bn, bf16_acts=bf16)
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -89,19 +130,25 @@ def quant_matmul(
     *,
     block: tuple[int, int, int] | None = None,
 ) -> jax.Array:
-    """y = x @ dequant(W).  x: (..., K); w_packed: (K, N//per)."""
+    """y = x @ dequant(W).  x: (..., K); w_packed: (K//per, N)."""
+    return _on_mesh(
+        functools.partial(_quant_matmul, bits=bits, block=block), (0,),
+        x, w_packed, jnp.asarray(scale), jnp.asarray(zero))
+
+
+def _quant_matmul(x, w_packed, scale, zero, *, bits, block):
     per = 8 // bits
     lead = x.shape[:-1]
     m = math.prod(lead)
     k = x.shape[-1]
-    n = w_packed.shape[1] * per
+    n = w_packed.shape[1]
     bm, bn, bk = block or _choose(m, k, n, bits, bf16=x.dtype == jnp.bfloat16)
     _bump("quant_matmul")
     x2 = _pad_to(x.reshape(m, k), (bm, bk))
-    wp = _pad_to(w_packed, (bk, bn // per))
+    wp = _pad_to(w_packed, (bk // per, bn))
     y = quant_matmul_pallas(
-        x2, wp, jnp.asarray(scale), jnp.asarray(zero), bits,
-        bm=bm, bn=bn, bk=bk, interpret=_interpret(),
+        x2, wp, scale, zero, bits,
+        bm=bm, bn=bn, bk=bk, interpret=interpret_mode(),
     )
     return y[:m, :n].reshape(*lead, n)
 
@@ -111,6 +158,11 @@ def splitq_matmul(
     block: tuple[int, int, int] | None = None,
 ) -> jax.Array:
     """Fused k-plane SplitQuantV2 matmul. x: (..., K); sq.shape == (K, N)."""
+    return _on_mesh(functools.partial(_splitq_matmul, block=block), (0,),
+                    x, sq)
+
+
+def _splitq_matmul(x, sq, *, block):
     per = 8 // sq.bits
     lead = x.shape[:-1]
     m = math.prod(lead)
@@ -120,10 +172,10 @@ def splitq_matmul(
                                   bf16=x.dtype == jnp.bfloat16)
     _bump("splitq_matmul")
     x2 = _pad_to(x.reshape(m, k), (bm, bk))
-    planes = _pad_to(sq.planes, (1, bk, bn // per))
+    planes = _pad_to(sq.planes, (1, bk // per, bn))
     y = splitq_matmul_pallas(
         x2, planes, sq.scales, sq.zeros, sq.bits,
-        bm=bm, bn=bn, bk=bk, interpret=_interpret(),
+        bm=bm, bn=bn, bk=bk, interpret=interpret_mode(),
     )
     return y[:m, :n].reshape(*lead, n)
 
@@ -135,6 +187,11 @@ def splitq_packed_matmul(
     block: tuple[int, int, int] | None = None,
 ) -> jax.Array:
     """6-bit packed SplitQuantV2 matmul. x: (..., K)."""
+    return _on_mesh(functools.partial(_splitq_packed_matmul, block=block),
+                    (0,), x, psq)
+
+
+def _splitq_packed_matmul(x, psq, *, block):
     per = 8 // psq.bits
     lead = x.shape[:-1]
     m = math.prod(lead)
@@ -144,11 +201,11 @@ def splitq_packed_matmul(
                                   bf16=x.dtype == jnp.bfloat16)
     _bump("splitq_packed_matmul")
     x2 = _pad_to(x.reshape(m, k), (bm, bk))
-    codes = _pad_to(psq.codes, (bk, bn // per))
-    cids = _pad_to(psq.cids, (bk, bn // 4))
+    codes = _pad_to(psq.codes, (bk // per, bn))
+    cids = _pad_to(psq.cids, (bk // 4, bn))
     y = splitq_packed_matmul_pallas(
         x2, codes, cids, psq.scales, psq.zeros, psq.bits,
-        bm=bm, bn=bn, bk=bk, interpret=_interpret(),
+        bm=bm, bn=bn, bk=bk, interpret=interpret_mode(),
     )
     return y[:m, :n].reshape(*lead, n)
 
@@ -165,6 +222,12 @@ def splitq_packed_group_matmul(
     x is read once instead of once per member — at decode this halves the
     activation HBM traffic of the attention + MLP input projections.
     """
+    return _on_mesh(
+        functools.partial(_splitq_packed_group_matmul, block=block), (0,),
+        x, grp)
+
+
+def _splitq_packed_group_matmul(x, grp, *, block):
     per = 8 // grp.bits
     lead = x.shape[:-1]
     m = math.prod(lead)
@@ -179,8 +242,8 @@ def splitq_packed_group_matmul(
     assert grp.align % bn == 0, (grp.align, bn)
     _bump("splitq_packed_group_matmul")
     x2 = _pad_to(x.reshape(m, k), (bm, bk))
-    codes = _pad_to(grp.codes, (bk, n_tot // per))
-    cids = _pad_to(grp.cids, (bk, n_tot // 4))
+    codes = _pad_to(grp.codes, (bk // per, n_tot))
+    cids = _pad_to(grp.cids, (bk // 4, n_tot))
     starts, off = [], 0
     for pw in padded:
         starts.append(off // bn)
@@ -188,7 +251,7 @@ def splitq_packed_group_matmul(
     y = splitq_packed_matmul_pallas(
         x2, codes, cids, grp.scales, grp.zeros, grp.bits,
         bm=bm, bn=bn, bk=bk, group_starts=tuple(starts),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     out, off = [], 0
     for w, pw in zip(grp.widths, padded):
@@ -197,21 +260,46 @@ def splitq_packed_group_matmul(
     return out
 
 
+def paged_attention(
+    q: jax.Array,           # (B, KV, G, hd)
+    k_pages: jax.Array,     # (P, page, KV, hd)
+    v_pages: jax.Array,     # (P, page, KV, hd)
+    page_table: jax.Array,  # (B, NP)
+    lengths: jax.Array,     # (B,)
+    window: jax.Array | int = 0,
+    chunk: jax.Array | int = 0,
+) -> jax.Array:
+    """Paged decode attention (``kernels.paged_attention``) over a page
+    pool; the pools are gathered whole on a serving mesh, so page ids stay
+    global."""
+    def run(q, k_pages, v_pages, page_table, lengths, window, chunk):
+        return paged_mod.paged_attention_pallas(
+            q, k_pages, v_pages, page_table, lengths, window=window,
+            chunk=chunk, interpret=interpret_mode())
+
+    return _on_mesh(run, (0, 3, 4), q, k_pages, v_pages, page_table,
+                    lengths, jnp.asarray(window, jnp.int32),
+                    jnp.asarray(chunk, jnp.int32))
+
+
 def quantize_pack(
     w: jax.Array, scale: jax.Array, zero: jax.Array, bits: int,
     *, block: tuple[int, int] = (256, 512),
 ) -> jax.Array:
-    """Fused quantize+pack. w: (R, C) -> (R, C//per) int8, C padded entries
-    are quantized zeros (caller slices by logical shape)."""
-    br, bc = block
+    """Fused quantize+pack. w: (R, C) -> (packed_rows(R)//per, C) int8,
+    the layout of ``repro.core.quantize.pack_codes``."""
     per = 8 // bits
     r, c = w.shape
+    rows = packed_rows(r)
+    br, bc = block
+    if rows <= PACK_SLAB:
+        br = rows  # one slab: the tile is the whole tensor
     w2 = _pad_to(w, (br, bc))
     out = quantize_pack_pallas(
-        w2, jnp.asarray(scale), jnp.asarray(zero), bits,
-        br=br, bc=bc, interpret=_interpret(),
+        w2, jnp.asarray(scale), jnp.asarray(zero), bits, rows=r,
+        br=br, bc=bc, interpret=interpret_mode(),
     )
-    return out[:r, : (c + per - 1) // per]
+    return out[: rows // per, :c]
 
 
 def kmeans_assign_reduce(
@@ -230,7 +318,7 @@ def kmeans_assign_reduce(
     mask = _pad_to(mask, (br, bc))
     return kmeans_assign_reduce_pallas(
         x2, mask, centroids, k=centroids.shape[0],
-        br=br, bc=bc, interpret=_interpret(),
+        br=br, bc=bc, interpret=interpret_mode(),
     )
 
 

@@ -13,15 +13,13 @@ from repro.core.quantize import unpack_codes
 
 def quant_matmul_ref(
     x: jax.Array,          # (M, K) activations, fp32/bf16
-    w_packed: jax.Array,   # (K, N // per) packed int-b codes along N
+    w_packed: jax.Array,   # (Kp // per, N) packed int-b codes along K
     scale: jax.Array,      # per-tensor () or per-group (K // G, N)
     zero: jax.Array,       # same shape as scale
     bits: int,
 ) -> jax.Array:
-    """y = x @ dequant(W). Weights packed along the last (N) axis."""
-    per = 8 // bits
-    n = w_packed.shape[-1] * per
-    q = unpack_codes(w_packed, bits, out_len=n).astype(jnp.float32)
+    """y = x @ dequant(W). Weights packed along the K (row) axis."""
+    q = unpack_codes(w_packed, bits, out_len=x.shape[-1]).astype(jnp.float32)
     if scale.ndim == 0:
         w = (q - zero) / scale
     else:
@@ -34,45 +32,43 @@ def quant_matmul_ref(
 
 def splitq_matmul_ref(
     x: jax.Array,          # (M, K)
-    planes: jax.Array,     # (k, K, N // per) packed int-b codes
+    planes: jax.Array,     # (k, Kp // per, N) packed int-b codes
     scales: jax.Array,     # (k,)
     zeros: jax.Array,      # (k,)
     bits: int,
 ) -> jax.Array:
     """Fused SplitQuantV2 matmul: y = x @ sum_c dequant(plane_c)."""
-    per = 8 // bits
-    n = planes.shape[-1] * per
-    w = jnp.zeros((planes.shape[1], n), jnp.float32)
+    kdim = x.shape[-1]
+    w = jnp.zeros((kdim, planes.shape[-1]), jnp.float32)
     for c in range(planes.shape[0]):
-        q = unpack_codes(planes[c], bits, out_len=n).astype(jnp.float32)
+        q = unpack_codes(planes[c], bits, out_len=kdim).astype(jnp.float32)
         w = w + (q - zeros[c]) / scales[c]
     return jnp.dot(x.astype(jnp.float32), w).astype(x.dtype)
 
 
 def splitq_packed_matmul_ref(
     x: jax.Array,          # (M, K)
-    codes: jax.Array,      # (K, N // per) packed int-b codes
-    cids: jax.Array,       # (K, N // 4) packed 2-bit cluster ids
+    codes: jax.Array,      # (Kp // per, N) packed int-b codes
+    cids: jax.Array,       # (Kp // 4, N) packed 2-bit cluster ids
     scales: jax.Array,     # (k,)
     zeros: jax.Array,      # (k,)
     bits: int,
 ) -> jax.Array:
     """Beyond-paper 6-bit layout: w_ij = (q_ij - Z[cid_ij]) / S[cid_ij]."""
-    per = 8 // bits
-    n = codes.shape[-1] * per
-    q = unpack_codes(codes, bits, out_len=n).astype(jnp.float32)
-    cid = unpack_codes(cids, 2, out_len=n).astype(jnp.int32) & 0x3
+    kdim = x.shape[-1]
+    q = unpack_codes(codes, bits, out_len=kdim).astype(jnp.float32)
+    cid = unpack_codes(cids, 2, out_len=kdim).astype(jnp.int32) & 0x3
     w = (q - zeros[cid]) / scales[cid]
     return jnp.dot(x.astype(jnp.float32), w).astype(x.dtype)
 
 
 def quantize_pack_ref(
-    w: jax.Array,          # (R, C), C divisible by 8//bits
+    w: jax.Array,          # (R, C)
     scale: jax.Array,      # ()
     zero: jax.Array,       # ()
     bits: int,
 ) -> jax.Array:
-    """Fused quantize+pack: codes = clip(round(S*w)+Z), packed along C."""
+    """Fused quantize+pack: codes = clip(round(S*w)+Z), packed along R."""
     from repro.core.quantize import pack_codes
 
     q = jnp.round(scale * w.astype(jnp.float32)) + zero

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant_matmul import _unpack_tile
+from repro.kernels.quant_matmul import MATMUL_PARAMS, _unpack_tile
 
 
 def _splitq_kernel(
@@ -33,7 +33,7 @@ def _splitq_kernel(
     w = None
     for c in range(k):  # static unroll: k == 3
         q = _unpack_tile(planes_ref[c], bits).astype(jnp.float32)
-        wc = (q - z_ref[c, 0]) * s_ref[c, 0]  # s_ref holds reciprocals
+        wc = (q - z_ref[c]) * s_ref[c]  # s_ref holds reciprocals
         w = wc if w is None else w + wc
     acc_ref[...] += jax.lax.dot(
         x_ref[...].astype(jnp.float32), w, preferred_element_type=jnp.float32
@@ -49,7 +49,7 @@ def _splitq_kernel(
 )
 def splitq_matmul_pallas(
     x: jax.Array,       # (M, K)
-    planes: jax.Array,  # (k, K, N//per) int8 carriers
+    planes: jax.Array,  # (k, K//per, N) int8 carriers
     scales: jax.Array,  # (k,)
     zeros: jax.Array,   # (k,)
     bits: int,
@@ -61,33 +61,26 @@ def splitq_matmul_pallas(
     per = 8 // bits
     kclusters = planes.shape[0]
     m, kdim = x.shape
-    n = planes.shape[2] * per
+    n = planes.shape[2]
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0
+    assert planes.shape[1] * per == kdim
     nk = kdim // bk
-    inv_s = (1.0 / scales).reshape(kclusters, 1).astype(jnp.float32)
-    z = zeros.reshape(kclusters, 1).astype(jnp.float32)
-    grid = (m // bm, n // bn, nk)
-    kwargs = {}
-    if not interpret:
-        # (M, N) parallel + K arbitrary => Mosaic double-buffers the packed
-        # plane DMA against the MXU sweep (decode is weight-BW-bound).
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+    inv_s = (1.0 / scales).astype(jnp.float32)
+    z = zeros.astype(jnp.float32)
     return pl.pallas_call(
         functools.partial(_splitq_kernel, bits=bits, nk=nk, k=kclusters),
-        grid=grid,
+        grid=(m // bm, n // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec(
-                (kclusters, bk, bn // per), lambda i, j, kk: (0, kk, j)
+                (kclusters, bk // per, bn), lambda i, j, kk: (0, kk, j)
             ),
-            pl.BlockSpec((kclusters, 1), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((kclusters, 1), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=None if interpret else MATMUL_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(x, planes, inv_s, z)

@@ -21,6 +21,11 @@ Pipelining: grid dims (M, N) are declared ``parallel`` and the K sweep
 ``arbitrary`` so Mosaic double-buffers the packed weight DMA against the
 MXU work (weight HBM streaming is the decode bottleneck this kernel
 exists to hide).
+
+Layout: codes and cluster ids are both packed along K in whole slabs (see
+``repro.core.quantize.pack_codes``), so the code tile (bk/per, bn) and the
+id tile (bk/4, bn) of one output block unpack to the same (bk, bn) rows.
+The LUTs sit in SMEM and are read as scalars.
 """
 from __future__ import annotations
 
@@ -31,14 +36,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant_matmul import _unpack_tile
+from repro.kernels.quant_matmul import MATMUL_PARAMS, _unpack_tile
 
 
 def _lut_select(cid: jax.Array, lut_ref, k: int) -> jax.Array:
-    """out[i] = lut[cid[i]] via select chain; cid int32, lut_ref (k, 1)."""
-    out = jnp.full(cid.shape, lut_ref[0, 0], jnp.float32)
+    """out[i] = lut[cid[i]] via select chain; cid int32, lut_ref (k,)."""
+    out = jnp.full(cid.shape, lut_ref[0], jnp.float32)
     for c in range(1, k):
-        out = jnp.where(cid == c, lut_ref[c, 0], out)
+        out = jnp.where(cid == c, lut_ref[c], out)
     return out
 
 
@@ -50,9 +55,9 @@ def _lut_select_grouped(cid, g, lut_ref, k: int, groups: int) -> jax.Array:
     same k-1 selects as the ungrouped path."""
     vals = []
     for c in range(k):
-        v = lut_ref[c, 0]
+        v = lut_ref[c]
         for gg in range(1, groups):
-            v = jnp.where(g == gg, lut_ref[gg * k + c, 0], v)
+            v = jnp.where(g == gg, lut_ref[gg * k + c], v)
         vals.append(v)
     out = jnp.full(cid.shape, vals[0], jnp.float32)
     for c in range(1, k):
@@ -96,8 +101,8 @@ def _splitq_packed_kernel(
 )
 def splitq_packed_matmul_pallas(
     x: jax.Array,      # (M, K)
-    codes: jax.Array,  # (K, N//per) int8 carriers
-    cids: jax.Array,   # (K, N//4) packed 2-bit ids
+    codes: jax.Array,  # (K//per, N) int8 carriers
+    cids: jax.Array,   # (K//4, N) packed 2-bit ids
     scales: jax.Array, # (G*k,)  member-major LUT (G==1 for a single tensor)
     zeros: jax.Array,  # (G*k,)
     bits: int,
@@ -111,34 +116,28 @@ def splitq_packed_matmul_pallas(
     groups = max(1, len(group_starts))
     k = scales.shape[0] // groups
     m, kdim = x.shape
-    n = codes.shape[1] * per
+    n = codes.shape[1]
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0
-    assert bn % 4 == 0
+    assert codes.shape[0] * per == kdim and cids.shape[0] * 4 == kdim
     nk = kdim // bk
-    inv_s = (1.0 / scales).reshape(groups * k, 1).astype(jnp.float32)
-    z = zeros.reshape(groups * k, 1).astype(jnp.float32)
-    grid = (m // bm, n // bn, nk)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+    inv_s = (1.0 / scales).astype(jnp.float32)
+    z = zeros.astype(jnp.float32)
     return pl.pallas_call(
         functools.partial(
             _splitq_packed_kernel, bits=bits, nk=nk, k=k,
             group_starts=group_starts,
         ),
-        grid=grid,
+        grid=(m // bm, n // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn // per), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bk, bn // 4), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((groups * k, 1), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((groups * k, 1), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((bk // per, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bk // 4, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=None if interpret else MATMUL_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(x, codes, cids, inv_s, z)

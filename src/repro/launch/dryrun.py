@@ -103,8 +103,6 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
         # it for reference, but use the loop-aware HLO cost model for the
         # roofline terms (see roofline/hlocost.py).
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # jax <= 0.4.x: one dict per computation
-            cost = cost[0] if cost else {}
         rec["cost_analysis_xla"] = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -7,18 +7,23 @@ overrides the host device count and everything else must see 1 CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (data, model) or 2x16x16 (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (reduced test meshes, elastic re-meshes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (reduced test meshes, elastic re-meshes).
+
+    Every axis is ``Auto``: placement comes from the sharding rules and
+    ``with_sharding_constraint`` hints (``runtime.sharding``), which JAX
+    accepts only on Auto axes — ``jax.make_mesh`` defaults to Explicit."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_shards(mesh) -> int:

@@ -12,8 +12,8 @@ Nothing here is modeled: the lowered HLO contains the engine's in-graph
 dequant + matmul exactly as serving runs it (codes/cids planes sharded on
 the output dim, per-shard (S, Z) LUT reads replicated), the cache follows
 the serving contract (per-slot ``len: (B,)``, slot dim batch-sharded over
-``data``), and the per-shard autotuned block dispatch is the one
-``tp_shards()`` keys inside the trace. Weight HBM traffic per decode step
+``data``), and each Pallas kernel runs per device in a ``shard_map``
+with its weights gathered for the call. Weight HBM traffic per decode step
 drops from bf16 (16 bit/wt) to 6 bit/wt.
 
     PYTHONPATH=src python -m repro.launch.qserve_dryrun --arch internlm2-20b
@@ -61,7 +61,7 @@ def main(argv=None):
     def serve_step(qparams, tokens, cache):
         # hints entered INSIDE the traced body (trace-time capture), same
         # as BatchedServer's decode closure: exact-TP act_constraints plus
-        # per-shard autotune keys via tp_shards()
+        # the kernels' shard_map over this mesh
         with shd.sharding_hints(mesh, exact_tp=True):
             return model.decode_step(qparams, tokens, cache)
 
@@ -90,17 +90,17 @@ def main(argv=None):
     n_params = roof.count_params(aparams)
 
     # The engine execution plan this lowering dispatched: block choices for
-    # each distinct quantized matmul at its PER-SHARD shape (batch over
-    # `data`, projection N over `model`) — the same division tp_shards()
-    # applies inside the trace, suitable for seeding SPLITQ_TUNE_CACHE.
+    # each distinct quantized matmul as a device runs it (its batch rows
+    # over `data`, the whole projection — weights are gathered for the
+    # kernel call), suitable for seeding SPLITQ_TUNE_CACHE.
     m_dec = max(1, shape.global_batch // n_data)  # decode: 1 token/sequence
     h, kv, hd, d, ff = (cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_model,
                         cfg.d_ff)
     proj_shapes = {
-        "wqkv": (d, (h * hd + 2 * kv * hd) // n_model),
-        "wo": (h * hd // n_model, d),
-        "w_gateup": (d, 2 * ff // n_model),
-        "w_down": (ff // n_model, d),
+        "wqkv": (d, h * hd + 2 * kv * hd),
+        "wo": (h * hd, d),
+        "w_gateup": (d, 2 * ff),
+        "w_down": (ff, d),
     }
     engine_blocks = {
         name: list(choose_block(m_dec, k_, n_, policy.bits))

@@ -94,6 +94,7 @@ import numpy as np
 
 from repro.kvcache import (PagePoolGroup, PrefixIndex, copy_page, pages_for,
                            read_pages, write_pages)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import _RECURRENT_KEYS, reset_slots
 from repro.obs import DEFAULT_CAP, JaxProfile, Observability, compile_counts
 from repro.obs.trace import now as _now
@@ -423,8 +424,8 @@ class BatchedServer:
             self._decode = jax.jit(model.decode_step)
         else:
             # the hints context is entered INSIDE the traced body: the
-            # exact-TP act_constraints (and the per-shard autotune keys via
-            # tp_shards) are captured at trace time, like steps.py
+            # exact-TP act_constraints (and the kernels' shard_map over the
+            # serving mesh) are captured at trace time, like steps.py
             def _decode_fn(params, tokens, cache, active):
                 with plan.hints():
                     return model.decode_step(params, tokens, cache,
@@ -2181,6 +2182,7 @@ def build_engine(args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     (cfg, model, params, draft_params, w_bytes, mesh,
      probe_params) = build_engine(args)
 

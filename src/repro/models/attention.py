@@ -46,10 +46,14 @@ def _flash_scope():
 
 
 def _use_paged_kernel() -> bool:
-    """Route paged decode through the Pallas kernel. On TPU it runs
-    compiled; tests monkeypatch this to exercise the dispatch glue in
-    interpret mode on CPU (CI would otherwise never trace it)."""
-    return jax.default_backend() == "tpu"
+    """Route paged decode through the Pallas kernel wherever it runs
+    compiled (a TPU; other accelerators raise in ``interpret_mode``). On
+    the CPU decode takes the XLA path; tests monkeypatch this to exercise
+    the dispatch glue in interpret mode (CI would otherwise never trace
+    it)."""
+    from repro.kernels.ops import interpret_mode
+
+    return not interpret_mode()
 
 
 def init_attention(rng, cfg, dtype) -> dict:
@@ -291,15 +295,14 @@ def attention_block(
         inc = s if seq_lens is None else seq_lens.astype(jnp.int32)
         k_len = starts + inc
         if s == 1 and _use_paged_kernel():
-            from repro.kernels.paged_attention import paged_attention_pallas
+            from repro.kernels.ops import paged_attention
 
             # kv-major head split: h = (kvh, g), matching _sdpa's grouped
             # decode reshape and the kernel's (B, KV, G, hd) layout
             qg = q[:, 0].reshape(b, kvh, h // kvh, hd)
-            og = paged_attention_pallas(
+            og = paged_attention(
                 qg, new_cache[0], new_cache[1], page_table, k_len,
                 window=layer_window, chunk=layer_chunk,
-                interpret=jax.default_backend() != "tpu",
             )
             out = og.reshape(b, 1, h, hd)
         else:

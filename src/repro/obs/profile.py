@@ -109,9 +109,9 @@ class JaxProfile:
     """Tick-gated ``jax.profiler`` trace around the serving loop.
 
     Starts the profiler at the first decode tick and stops it after
-    ``ticks`` more (or at run end, whichever comes first). Profiler
-    availability is probed defensively: a missing/broken profiler import
-    must degrade to a no-op, never take down serving."""
+    ``ticks`` more (or at run end, whichever comes first). A trace that
+    cannot start or stop fails the run: a run asked to profile that
+    quietly produced no trace would be read as a measured one."""
 
     def __init__(self, outdir: str, ticks: int = 8):
         if ticks < 1:
@@ -126,27 +126,19 @@ class JaxProfile:
         if self.done:
             return
         if not self.active:
-            try:
-                import jax
+            import jax
 
-                jax.profiler.start_trace(self.outdir)
-            except Exception:
-                self.done = True  # profiler unavailable: stay a no-op
-                return
+            jax.profiler.start_trace(self.outdir)
             self.active = True
             self._start_tick = tick
         elif tick - self._start_tick >= self.ticks:
             self.stop()
 
     def stop(self) -> None:
-        if not self.active:
-            self.done = True
-            return
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
-        self.active = False
         self.done = True
+        if not self.active:
+            return
+        import jax
+
+        self.active = False
+        jax.profiler.stop_trace()

@@ -57,10 +57,7 @@ def compressed_psum_mean(
     """Mean over ``axis_name`` with int8 wire traffic (call inside
     shard_map). flat_grad: (n,) fp32, size divisible by BLOCK and by the
     axis size."""
-    try:
-        n_shards = jax.lax.axis_size(axis_name)
-    except AttributeError:  # jax <= 0.4.x: constant-folds to a python int
-        n_shards = jax.lax.psum(1, axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     q, s = _quant_block(flat_grad)
     nblk = q.shape[0]
     # reduce-scatter decomposition: all_to_all int8 chunks, local fp32 sum
@@ -106,13 +103,6 @@ def make_compressed_allreduce(mesh: Mesh, axis_name: str = "data"):
     too) — each shard corrects its own compression error.
     Wire traffic per hop is int8 + fp32/BLOCK scales ≈ 26.6% of fp32.
     """
-    try:
-        shard_map = jax.shard_map  # top-level API in new jax
-        smap_kw = {"check_vma": False}
-    except AttributeError:  # jax <= 0.4.x
-        from jax.experimental.shard_map import shard_map
-        smap_kw = {"check_rep": False}
-
     n_ax = mesh.shape[axis_name]
 
     def allreduce(tree: Any, err: Any):
@@ -129,11 +119,11 @@ def make_compressed_allreduce(mesh: Mesh, axis_name: str = "data"):
             new_err = g - quant_roundtrip(g)  # local quantization residual
             return reduced[None], new_err[None]
 
-        reduced, new_err = shard_map(
+        reduced, new_err = jax.shard_map(
             inner, mesh=mesh,
             in_specs=P(axis_name, None),
             out_specs=(P(None), P(axis_name, None)),
-            **smap_kw,
+            check_vma=False,
         )(big2)
         reduced = reduced[0, : n]
         mean = jnp.tile(reduced, n_ax)[: big.size]

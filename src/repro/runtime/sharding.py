@@ -511,8 +511,8 @@ def sharding_hints(mesh: Mesh, exact_tp: bool = False):
 
     ``exact_tp=True`` switches to the serving contract: matmul inputs and
     outputs replicate over ``model`` (only exact all-gathers, bit-identical
-    streams) and kernel autotuning keys by the per-shard output width
-    (:func:`tp_shards`)."""
+    streams) and Pallas kernels run per device under a ``shard_map``
+    (:func:`serving_mesh`, ``repro.kernels.ops``)."""
     prev = (getattr(_CTX, "mesh", None), getattr(_CTX, "exact", False))
     _CTX.mesh, _CTX.exact = mesh, exact_tp
     try:
@@ -521,15 +521,12 @@ def sharding_hints(mesh: Mesh, exact_tp: bool = False):
         _CTX.mesh, _CTX.exact = prev
 
 
-def tp_shards() -> int:
-    """TP degree the current trace shards matmul outputs over (1 = none).
-
-    Kernel wrappers divide their N by this to key the autotune cache by the
-    per-shard matmul shape a device actually runs."""
+def serving_mesh() -> Mesh | None:
+    """The mesh of the exact-TP serving hints the current trace runs
+    under, or None outside them."""
     if not getattr(_CTX, "exact", False):
-        return 1
-    mesh = getattr(_CTX, "mesh", None)
-    return mesh.shape.get("model", 1) if mesh is not None else 1
+        return None
+    return getattr(_CTX, "mesh", None)
 
 
 def _batch_axes_of(mesh: Mesh):

@@ -520,7 +520,10 @@ async def _run_service(args) -> int:
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = _service_parser().parse_args(argv)
+    enable_compile_cache()
     if args.selfcheck:
         return asyncio.run(_run_selfcheck(args))
     return asyncio.run(_run_service(args))

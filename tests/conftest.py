@@ -1,9 +1,11 @@
 """Suite-wide setup. MUST run before jax is first imported.
 
-The CI/dev container ships libtpu but has no TPU: without an explicit
-platform, jax's backend probe blocks ~8 minutes per process before falling
-back to CPU (this alone made the suite take half an hour). Tests are
-interpret-mode CPU by design; export JAX_PLATFORMS yourself to override.
+Tests run on the CPU, with Pallas kernels in interpret mode
+(``repro.kernels.ops.interpret_mode``). Without an explicit platform, jax
+would try the TPU library first on a machine that has it installed; the
+suite pins the CPU. The TPU path is covered by compiling for a described
+chip (``tests/test_tpu_compile.py``) and run on a chip by
+``python chip_smoke.py``. Export JAX_PLATFORMS yourself to override.
 """
 import os
 

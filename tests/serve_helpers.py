@@ -9,6 +9,7 @@ change a knob here and every suite sees the same workload.
 """
 import dataclasses
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -53,22 +54,29 @@ def serve_once(model, params, reqs, **kw):
     return {r.rid: r.out for r in reqs}, stats
 
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
 def subprocess_env(devices=8):
     """The hermetic environment the subprocess smokes run under: repo
-    sources on the path, fake host devices for mesh runs, nothing
-    inherited that could vary between CI and a dev shell."""
-    return {
-        "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+    sources on the path, fake host devices for mesh runs, the CPU backend,
+    and from the caller only where to find programs and where to write."""
+    env = {
+        "PYTHONPATH": str(REPO_ROOT / "src"),
         "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
     }
+    for key in ("PATH", "HOME", "TMPDIR"):
+        if key in os.environ:
+            env[key] = os.environ[key]
+    return env
 
 
 def run_python(code, timeout=600, devices=8):
     """``python -c code`` in the hermetic env (inline mesh smokes)."""
     return subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=timeout, cwd="/root/repo", env=subprocess_env(devices),
+        timeout=timeout, cwd=REPO_ROOT, env=subprocess_env(devices),
     )
 
 
@@ -76,6 +84,6 @@ def run_module(module, args, timeout=600, devices=8):
     """``python -m module *args`` in the hermetic env (CLI smokes)."""
     return subprocess.run(
         [sys.executable, "-m", module, *args], capture_output=True,
-        text=True, timeout=timeout, cwd="/root/repo",
+        text=True, timeout=timeout, cwd=REPO_ROOT,
         env=subprocess_env(devices),
     )

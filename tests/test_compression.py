@@ -1,13 +1,14 @@
 """Gradient compression: quantizer properties + multi-device collective
 exactness (subprocess with 8 fake devices so the main test process keeps
 seeing 1 CPU device)."""
-import os
 import subprocess
 import sys
 import textwrap
 
 import jax.numpy as jnp
 import numpy as np
+
+from serve_helpers import REPO_ROOT, subprocess_env
 
 from repro.optim.compression import BLOCK, quant_roundtrip
 
@@ -80,11 +81,7 @@ def test_compressed_allreduce_multidevice():
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS],
         capture_output=True, text=True, timeout=300,
-        # JAX_PLATFORMS=cpu: skip the ~8-minute TPU-backend probe (the
-        # container ships libtpu but has no TPU)
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo",
+        env=subprocess_env(8), cwd=REPO_ROOT,
     )
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout

@@ -21,6 +21,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import QuantPolicy, restructure
+from repro.core.quantize import packed_rows
 from repro.core.split import group_packed, split_quantize_packed
 from repro.engine import autotune
 from repro.engine.executable import supports_kernel_path, weight_bytes
@@ -163,8 +164,9 @@ def test_autotuner_blocks_valid_for_odd_shapes(m, k, n, bits):
     bm, bn, bk = autotune.heuristic_block(m, k, n, bits)
     assert bm % 8 == 0 and bm >= 8          # fp32 sublane
     assert bn % 128 == 0                    # lane
-    assert bk % 128 == 0
-    assert bn % 4 == 0                      # cid packing contract
+    # K tile covers whole packing slabs: 128-row multiples, or the whole
+    # K of a single-slab weight
+    assert bk % 128 == 0 or bk == packed_rows(k) < 128
     assert autotune._vmem_bytes(bm, bn, bk, bits) <= autotune.VMEM_BUDGET
     # bf16 activations need 16-row sublane alignment
     bm16, _, _ = autotune.heuristic_block(m, k, n, bits, bf16_acts=True)
@@ -191,12 +193,12 @@ def test_tune_cache_roundtrip_and_dispatch(tmp_path, monkeypatch):
         assert autotune.choose_block(8, 256, 256, 4) == \
             autotune.heuristic_block(8, 256, 256, 4)
         raw = json.loads(path.read_text())
-        assert raw["blocks"]["16x1024x1024@4/d1"] == [128, 256, 128]
-        # per-shard entries live in their own /dS namespace: a block tuned
-        # for the 2-way-sharded width must not answer the global lookup
+        assert raw["blocks"]["16x1024x1024@4"] == [128, 256, 128]
+        # bf16-activation entries live in their own +bf16 namespace: a
+        # block tuned for bf16 activations must not answer the fp32 lookup
         autotune.get_cache().put(16, 1024, 512, 4, (128, 128, 128),
-                                 n_shards=2)
-        assert autotune.choose_block(16, 1024, 512, 4, n_shards=2) == \
+                                 bf16_acts=True)
+        assert autotune.choose_block(16, 1024, 512, 4, bf16_acts=True) == \
             (128, 128, 128)
         assert autotune.choose_block(16, 1024, 512, 4) == \
             autotune.heuristic_block(16, 1024, 512, 4)
@@ -210,12 +212,12 @@ def test_tune_cache_rejects_malformed_entries_at_load(tmp_path, monkeypatch):
     heuristic at LOAD time, not raise inside choose_block on the hot
     path."""
     path = tmp_path / "tune.json"
-    path.write_text(json.dumps({"schema": 2, "blocks": {
-        "16x1024x1024@4/d1": [128, 256],         # truncated by hand-edit
-        "8x256x256@4/d1": ["128", 128, 128],     # non-int member
-        "8x512x512@4/d1": None,                  # nulled entry
-        "8x768x768@4": [8, 128, 128],            # schema-1 GLOBAL-shape key
-        "4x128x128@4/d1": [8, 128, 128],         # the one valid entry
+    path.write_text(json.dumps({"schema": 3, "blocks": {
+        "16x1024x1024@4": [128, 256],            # truncated by hand-edit
+        "8x256x256@4": ["128", 128, 128],        # non-int member
+        "8x512x512@4": None,                     # nulled entry
+        "8x768x768@4/d1": [8, 128, 128],         # schema-2 shard-suffix key
+        "4x128x128@4": [8, 128, 128],            # the one valid entry
     }}))
     monkeypatch.setenv(autotune.ENV_CACHE, str(path))
     autotune.reset_cache()
@@ -226,9 +228,9 @@ def test_tune_cache_rejects_malformed_entries_at_load(tmp_path, monkeypatch):
             autotune.heuristic_block(8, 256, 256, 4)
         assert autotune.choose_block(8, 512, 512, 4) == \
             autotune.heuristic_block(8, 512, 512, 4)
-        # the stale schema-1 key (no /dS shard suffix) is dropped at load:
-        # it was tuned on a global shape and is ambiguous under sharding
-        assert "8x768x768@4" not in autotune.get_cache().table
+        # the stale schema-2 key (/dS shard suffix) is dropped at load: it
+        # was tuned for the column-packed layout
+        assert "8x768x768@4/d1" not in autotune.get_cache().table
         assert autotune.choose_block(4, 128, 128, 4) == (8, 128, 128)
     finally:
         monkeypatch.delenv(autotune.ENV_CACHE)
@@ -240,7 +242,7 @@ def test_tune_cache_hit_rechecks_vmem_budget():
     past this build's budget."""
     autotune.reset_cache()
     try:
-        huge = (8, 4096, 4096)  # aligned, but ~67 MB unpacked tile
+        huge = (8, 4096, 4096)  # aligned, but ~25 MB of double-buffered tiles
         assert autotune._vmem_bytes(*huge, 4) > autotune.VMEM_BUDGET
         autotune.get_cache().put(8, 4096, 4096, 4, huge)
         got = autotune.choose_block(8, 4096, 4096, 4)

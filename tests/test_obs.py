@@ -18,7 +18,6 @@ The contracts under test (the telemetry tentpole):
 * the Prometheus exposition round-trips through ``parse_prometheus``.
 """
 import dataclasses
-import os
 import subprocess
 import sys
 import textwrap
@@ -26,6 +25,8 @@ import textwrap
 import jax
 import numpy as np
 import pytest
+
+from serve_helpers import REPO_ROOT, subprocess_env
 
 from repro.configs import get_config
 from repro.core import QuantPolicy, restructure
@@ -365,6 +366,26 @@ def test_serve_cli_fails_loudly_on_trace_drops(monkeypatch):
     assert serve_mod.main(argv + ["--trace-cap", "2"]) != 0
 
 
+def test_serve_cli_fails_when_jax_profile_cannot_start(monkeypatch, tmp_path):
+    """--jax-profile whose trace cannot start fails the run: a run asked
+    to profile must not finish looking profiled with no trace written."""
+    import repro.launch.serve as serve_mod
+
+    tiny = dataclasses.replace(get_config("llama32-1b").reduced(),
+                               n_layers=2)
+    monkeypatch.setattr("repro.configs.get_config", lambda name: tiny)
+
+    def no_profiler(logdir, *a, **k):
+        raise RuntimeError(f"profiler unavailable for {logdir}")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", no_profiler)
+    argv = ["--no-reduced", "--bits", "0", "--batch", "2", "--requests",
+            "2", "--prompt-len", "4", "--gen", "3",
+            "--jax-profile", str(tmp_path / "trace")]
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        serve_mod.main(argv)
+
+
 # ---------------------------------------------------------------------------
 # Per-replica series sum to the aggregate (2x2 mesh, subprocess)
 # ---------------------------------------------------------------------------
@@ -430,10 +451,8 @@ _MESH_METRICS = """
 def test_per_replica_metrics_sum_to_aggregate_2x2():
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_MESH_METRICS)],
-        capture_output=True, text=True, timeout=600, cwd="/root/repo",
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8"},
+        capture_output=True, text=True, timeout=600, cwd=REPO_ROOT,
+        env=subprocess_env(8),
     )
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-3000:]
     assert "OK mesh-metrics" in r.stdout
