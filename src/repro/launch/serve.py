@@ -97,7 +97,6 @@ from repro.kvcache import (PagePoolGroup, PrefixIndex, copy_page, pages_for,
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import _RECURRENT_KEYS, reset_slots
 from repro.obs import DEFAULT_CAP, JaxProfile, Observability, compile_counts
-from repro.obs.trace import now as _now
 from repro.runtime import sharding as shd
 from repro.runtime.fault import PreemptionGuard, run_with_retries
 from repro.runtime.faultinject import FaultInjector
@@ -425,13 +424,15 @@ class BatchedServer:
         else:
             # the hints context is entered INSIDE the traced body: the
             # exact-TP act_constraints (and the kernels' shard_map over the
-            # serving mesh) are captured at trace time, like steps.py
-            def _decode_fn(params, tokens, cache, active):
+            # serving mesh) are captured at trace time, like steps.py. The
+            # name gives the program the one-chip path's name,
+            # ``jit_decode_step``, which profiles are read by.
+            def decode_step(params, tokens, cache, active):
                 with plan.hints():
                     return model.decode_step(params, tokens, cache,
                                              active=active)
 
-            self._decode = jax.jit(_decode_fn,
+            self._decode = jax.jit(decode_step,
                                    out_shardings=(None, self._cache_shd))
 
         def _prefill_fn(params, tokens, lengths, fresh, starts, cache):
@@ -489,10 +490,13 @@ class BatchedServer:
 
     # -- sampling / streaming -----------------------------------------------
 
-    def _pick_tokens(self, logits) -> Callable[[int], int]:
+    def _pick_tokens(self, logits, span) -> Callable[[int], int]:
         """Per-slot token chooser from device logits (B, 1, V). Greedy mode
         argmaxes ON DEVICE and transfers B ints; stochastic sampling needs
-        the full logits rows on the host (B x V, off the hot path).
+        the full logits rows on the host (B x V, off the hot path). The
+        transfer is the caller's ``fetch`` phase: ``span`` is the wave's or
+        step's span (``obs.span``), and the phase ends with the tokens (or
+        rows) on the host.
 
         Each request draws from its OWN stream seeded by (server seed,
         rid): the sampled tokens depend only on the request and the model,
@@ -506,10 +510,11 @@ class BatchedServer:
         neither is in play (serving streams stay untouched)."""
         eval_hooks = self.capture_logits or any(
             r is not None and r.force is not None for r in self.active)
-        if self.sampling["temperature"] <= 0.0 and not eval_hooks:
-            toks = np.asarray(jnp.argmax(logits[:, 0], -1))
-            return lambda i: int(toks[i])
-        rows = np.asarray(logits[:, 0])
+        with span.phase("fetch"):
+            if self.sampling["temperature"] <= 0.0 and not eval_hooks:
+                toks = np.asarray(jnp.argmax(logits[:, 0], -1))
+                return lambda i: int(toks[i])
+            rows = np.asarray(logits[:, 0])
 
         def pick(i: int) -> int:
             r = self.active[i]
@@ -541,15 +546,39 @@ class BatchedServer:
         the compat view over the new source of truth."""
         return self.timeline.legacy_events()
 
-    def _tl(self, kind: str, **fields) -> None:
+    def _tl(self, kind: str, **fields) -> dict:
         """Emit one scheduler-timeline record, stamped with the live
         scheduler state every record shares (active slots; pool free /
-        fragmentation in paged mode)."""
+        fragmentation in paged mode); returns the record."""
         fields["active"] = sum(1 for r in self.active if r is not None)
         if self.paged:
             fields["free_pages"] = self.alloc.free_pages
             fields["frag"] = round(self.alloc.fragmentation(), 4)
-        self.timeline.emit(kind, **fields)
+        return self.timeline.emit(kind, **fields)
+
+    def _close_round(self, rec: dict, span) -> None:
+        """Complete a prefill wave's, decode step's or verify round's
+        timeline record ``rec`` once the round is over. The record itself
+        is written right after the dispatch, while the device computes:
+        its pool statistics cost the host about a millisecond, which must
+        not stand between one step's tokens and the next dispatch. With
+        spans on (``obs.span``) its ``span`` field gets the round's ends,
+        its phases' ends and what it computed for whom, and its time from
+        dispatch to tokens on the host feeds the step timer under the
+        record's kind."""
+        if span.live:
+            rec["span"] = span.record()
+            ph = span.phases
+            self.obs.step_timer.observe(
+                rec["kind"], ph["fetch"][1] - ph["dispatch"][0])
+
+    @staticmethod
+    def _round_ends(span) -> tuple[float, float]:
+        """A round's request-span ends: dispatch start to tokens on the
+        host (zeros with spans off, when the tracer records nothing)."""
+        if not span.live:
+            return 0.0, 0.0
+        return span.phases["dispatch"][0], span.phases["fetch"][1]
 
     def _span(self, i: int, r: Request, kind: str, t0: float, t1: float,
               out_before: int, **kw) -> None:
@@ -686,16 +715,7 @@ class BatchedServer:
         because every step is a pure jitted function over an immutable
         cache pytree (re-running cannot double-apply a write), with
         ``OutOfPages`` excluded (deterministic resource condition: the
-        scheduler's relief path owns it, not the retry loop).
-
-        With a live registry the step additionally runs under the
-        per-seam ``StepTimer`` (``block_until_ready`` + wall clock into
-        ``serve_step_seconds{seam=...}``) — pure observation: blocking
-        changes when the host sees values, never what they are. A
-        ``NullRegistry`` run skips the wrapper entirely."""
-        if self.obs.step_timer.enabled:
-            inner = fn
-            fn = lambda: self.obs.step_timer.run(seam, inner)
+        scheduler's relief path owns it, not the retry loop)."""
         if self.inject is None:
             return fn()
 
@@ -790,6 +810,15 @@ class BatchedServer:
         free = [i for i in range(self.slots) if self.active[i] is None]
         if not free or not pending:
             return 0
+        with self.obs.span("host.serve.admit"):
+            admitted = self._admit(pending, free)
+        if admitted:
+            self._prefill_wave()
+        return admitted
+
+    def _admit(self, pending: list[Request], free: list[int]) -> int:
+        """Move picked requests from ``pending`` into ``free`` slots;
+        returns how many were admitted."""
         picked = self._select_for_slots(pending, free)
         if not picked:
             return 0
@@ -880,8 +909,6 @@ class BatchedServer:
                     req.acc = AcceptanceWindow(self.spec_floor,
                                                self.spec_window)
             admitted += 1
-        if admitted:
-            self._prefill_wave()
         return admitted
 
     def _admit_paged(self, i: int, req: Request) -> bool:
@@ -1242,12 +1269,56 @@ class BatchedServer:
         """ONE batched prefill advancing every mid-prompt row by one chunk
         (the whole remaining prompt when ``prefill_chunk == 0``). Rows whose
         prompt completes get their first token sampled from this wave's
-        logits at their own last real position."""
+        logits at their own last real position.
+
+        The wave is the span ``host.serve.prefill`` with the phases
+        ``prepare`` (host arrays, COW guards, table sync), ``dispatch``,
+        ``fetch`` (first tokens on the host) and ``emit``."""
         drafted = self._draft_prefill_wave()
         rows = [(i, r) for i, r in enumerate(self.active)
                 if r is not None and r.fed < len(self._seq(r))]
         if not rows:
             return drafted
+        with self.obs.span("host.serve.prefill") as sp:
+            with sp.phase("prepare"):
+                lb, sizes, args = self._wave_arrays(rows, sp)
+            with sp.phase("dispatch"):
+                logits, self.cache = self._call(
+                    "prefill", lambda: self._prefill(self.params, *args))
+            rec = self._tl("prefill", bucket=lb, rows=len(rows),
+                           tokens=int(sum(sizes.values())))
+            if self._snap_boundaries:
+                for i, r in rows:
+                    if (not r.indexed and r.fed > 0
+                            and r.fed % self.page_size == 0
+                            and r.fed not in r.snaps):
+                        r.snaps[r.fed] = {
+                            k: np.asarray(self.cache[k][:, i])
+                            for k in self._recurrent
+                        }
+            pick = self._pick_tokens(logits, sp)
+            with sp.phase("emit"):
+                t0, t1 = self._round_ends(sp)
+                for i, r in rows:
+                    before = len(r.out)
+                    if r.fed == len(self._seq(r)):
+                        self._index_prompt(i, r)
+                        if not r.out:
+                            # replayed requests skip this: their first
+                            # token(s) were emitted before preemption — the
+                            # replay tail's logits would re-derive out[-1],
+                            # which the next decode step re-feeds instead
+                            self._emit(r, pick(i))
+                    self._span(i, r, "prefill", t0, t1, before,
+                               fed=sizes[i])
+        self._close_round(rec, sp)
+        return True
+
+    def _wave_arrays(self, rows, span):
+        """Host arrays of one prefill wave over ``rows``, fed counts
+        advanced, COW guards run and the page table synced. Returns the
+        bucket width, each row's chunk and the prefill call's arguments
+        after ``params``; notes each row's ``[start, n]`` on ``span``."""
         if any(r.replay is not None for _, r in rows):
             # replay-restore recompute (spill-restored requests never
             # enter a wave — the spill tier's whole point)
@@ -1264,6 +1335,9 @@ class BatchedServer:
             sizes[i] = c
         lb = min(_bucket(max(sizes.values()), self.bucket_min), self.max_len)
         self.buckets_used.append(lb)
+        if span.live:
+            span.note(rids=[r.rid for _, r in rows],
+                      rows=[[r.fed, sizes[i]] for i, r in rows])
         tokens = np.zeros((self.slots, lb), np.int32)
         lengths = np.zeros((self.slots,), np.int32)
         fresh = np.zeros((self.slots,), bool)
@@ -1281,40 +1355,9 @@ class BatchedServer:
             r.fed += c
             self.prefill_tokens += c
         self._sync_table()
-
-        def _wave():
-            return self._prefill(
-                self.params, self._put(tokens), self._put(lengths),
-                self._put(fresh), self._put(starts), self.cache,
-            )
-
-        t0 = _now()
-        logits, self.cache = self._call("prefill", _wave)
-        t1 = _now()
-        self._tl("prefill", bucket=lb, rows=len(rows),
-                 tokens=int(sum(sizes.values())))
-        if self._snap_boundaries:
-            for i, r in rows:
-                if (not r.indexed and r.fed > 0
-                        and r.fed % self.page_size == 0
-                        and r.fed not in r.snaps):
-                    r.snaps[r.fed] = {
-                        k: np.asarray(self.cache[k][:, i])
-                        for k in self._recurrent
-                    }
-        pick = self._pick_tokens(logits)
-        for i, r in rows:
-            before = len(r.out)
-            if r.fed == len(self._seq(r)):
-                self._index_prompt(i, r)
-                if not r.out:
-                    # replayed requests skip this: their first token(s)
-                    # were emitted before preemption — the replay tail's
-                    # logits would re-derive out[-1], which the next
-                    # decode step re-feeds instead
-                    self._emit(r, pick(i))
-            self._span(i, r, "prefill", t0, t1, before, fed=sizes[i])
-        return True
+        args = (self._put(tokens), self._put(lengths), self._put(fresh),
+                self._put(starts), self.cache)
+        return lb, sizes, args
 
     def step(self) -> bool:
         """One decode step for all decode-ready slots; finished, empty and
@@ -1322,10 +1365,48 @@ class BatchedServer:
         advance). In growth mode each ready row first secures the page its
         write lands in (:meth:`_ensure_rows`) — a row whose growth fails,
         or that gets preempted by a NEIGHBOUR'S growth, sits the tick out
-        (greedy streams are invariant to the skipped tick)."""
+        (greedy streams are invariant to the skipped tick).
+
+        The step is the span ``host.serve.decode`` with the phases
+        ``prepare`` (page growth, host arrays, COW guards, table sync),
+        ``dispatch``, ``fetch`` (tokens on the host) and ``emit``."""
         ready = [(i, r) for i, r in enumerate(self.active)
                  if (r is not None and not r.done and r.out
                      and r.fed == len(self._seq(r)))]
+        if not ready:
+            return False
+        with self.obs.span("host.serve.decode") as sp:
+            with sp.phase("prepare"):
+                tokens, active = self._step_arrays(ready, sp)
+            if not active.any():
+                return False
+            with sp.phase("dispatch"):
+                logits, self.cache = self._call(
+                    "decode", lambda: self._decode(
+                        self.params, self._put(tokens), self.cache,
+                        active=self._put(active)))
+            rec = self._tl("decode", rows=int(active.sum()))
+            if self._probe_due():
+                rows_host = np.asarray(logits[:, 0])
+                for i, r in enumerate(self.active):
+                    if active[i]:
+                        self._probe_row(r, rows_host[i])
+            pick = self._pick_tokens(logits, sp)
+            with sp.phase("emit"):
+                t0, t1 = self._round_ends(sp)
+                for i, r in enumerate(self.active):
+                    if active[i]:
+                        before = len(r.out)
+                        self._emit(r, pick(i))
+                        self._span(i, r, "decode", t0, t1, before)
+        self._close_round(rec, sp)
+        return True
+
+    def _step_arrays(self, ready, span):
+        """Host arrays of one decode step: each ready row's last token and
+        the active mask, after page growth and COW guards; the page table
+        is synced when any row is active. Notes each active row's rid and
+        the position it feeds on ``span``."""
         grown: dict[int, bool] = {}
         if self.paged:
             for i, r in ready:
@@ -1347,32 +1428,14 @@ class BatchedServer:
                 # COW if the write page is somehow still shared
                 # (post-admission invariant: it never is)
                 self._cow_guard(i, r, len(r.prompt) + len(r.out) - 1, 1)
-        if not active.any():
-            return False
-        self._sync_table()
-
-        def _step():
-            return self._decode(
-                self.params, self._put(tokens), self.cache,
-                active=self._put(active),
-            )
-
-        t0 = _now()
-        logits, self.cache = self._call("decode", _step)
-        t1 = _now()
-        self._tl("decode", rows=int(active.sum()))
-        if self._probe_due():
-            rows_host = np.asarray(logits[:, 0])
-            for i, r in enumerate(self.active):
-                if active[i]:
-                    self._probe_row(r, rows_host[i])
-        pick = self._pick_tokens(logits)
-        for i, r in enumerate(self.active):
-            if active[i]:
-                before = len(r.out)
-                self._emit(r, pick(i))
-                self._span(i, r, "decode", t0, t1, before)
-        return True
+        if active.any():
+            self._sync_table()
+            if span.live:
+                live = [r for i, r in enumerate(self.active) if active[i]]
+                span.note(rids=[r.rid for r in live],
+                          positions=[len(r.prompt) + len(r.out) - 1
+                                     for r in live])
+        return tokens, active
 
     def _spec_ready(self, i: int, r: Request | None) -> bool:
         """Decode-ready for a speculative round: target prompt fully
@@ -1401,84 +1464,99 @@ class BatchedServer:
                 if self._spec_ready(i, r)]
         if not rows:
             return False
-        greedy = self.sampling["temperature"] <= 0.0
-        deg0 = self.spec.degraded_rounds
-        # capacity + degradation phase BEFORE any drafting: decide each
-        # row's draft budget under pool pressure / acceptance history
-        kks: dict[int, int] = {}
-        for i, r in rows:
-            if self.active[i] is not r:
-                continue  # preempted by an earlier row's growth
-            kk = (min(self.speculate, r.max_new - len(r.out) - 1)
-                  if r.draft_on else 0)
-            if kk > 0 and r.acc is not None and r.acc.degraded():
-                # persistent drafter divergence: decode plainly this round;
-                # aging the window lets drafting re-probe later
-                r.acc.age()
-                kk = 0
-                self.spec.degraded_rounds += 1
-            base_rows = len(r.prompt) + len(r.out)  # plain width-1 write
-            if self.paged and kk > 0:
-                if not self._ensure_rows(i, r, base_rows + kk,
-                                         preempt=False):
-                    # pool pressure: speculative HEADROOM is not worth
-                    # preempting a neighbour — fall back to plain decode
-                    # for this round
+        with self.obs.span("host.serve.verify") as sp:
+            rec = self._verify_round(rows, sp)
+        if rec is None:
+            return False
+        self._close_round(rec, sp)
+        return True
+
+    def _verify_round(self, rows, span) -> dict | None:
+        """The body of :meth:`_spec_round` under its span, with the phases
+        ``prepare`` (draft budgets, page growth, drafting, host arrays,
+        COW guards, table sync), ``dispatch``, ``fetch`` (scores on the
+        host) and ``emit`` (acceptance, rollback, emission). Returns the
+        round's timeline record, or None when no row could run."""
+        with span.phase("prepare"):
+            greedy = self.sampling["temperature"] <= 0.0
+            deg0 = self.spec.degraded_rounds
+            # capacity + degradation phase BEFORE any drafting: decide each
+            # row's draft budget under pool pressure / acceptance history
+            kks: dict[int, int] = {}
+            for i, r in rows:
+                if self.active[i] is not r:
+                    continue  # preempted by an earlier row's growth
+                kk = (min(self.speculate, r.max_new - len(r.out) - 1)
+                      if r.draft_on else 0)
+                if kk > 0 and r.acc is not None and r.acc.degraded():
+                    # persistent drafter divergence: decode plainly this
+                    # round; aging the window lets drafting re-probe later
+                    r.acc.age()
                     kk = 0
                     self.spec.degraded_rounds += 1
-            if self.paged and not self._ensure_rows(i, r, base_rows + kk):
-                continue  # preempted or blocked: sits this round out
-            kks[i] = kk
-        rows = [(i, r) for i, r in rows
-                if self.active[i] is r and i in kks]
-        if not rows:
-            return False
-        jobs = []
-        for i, r in rows:
-            if kks[i] > 0:
-                jobs.append((
-                    i,
-                    np.concatenate([r.prompt,
-                                    np.asarray(r.out, np.int32)]),
-                    kks[i],
-                ))
-        drafts: dict[int, list[int]] = {i: [] for i, _ in rows}
-        qdists: dict[int, np.ndarray] = {}
-        if jobs:
-            d, q = self.drafter.draft_round(
-                jobs, sampling=self.sampling,
-                rngs={i: self.active[i].rng for i, _, _ in jobs},
-            )
-            drafts.update(d)
-            qdists.update(q)
-        # one verify forward scores every row's chunk at once
-        width = self.speculate + 1
-        tokens = np.zeros((self.slots, width), np.int32)
-        lengths = np.zeros((self.slots,), np.int32)
-        base = np.zeros((self.slots,), np.int32)
-        for i, r in rows:
-            di = drafts[i]
-            base[i] = len(r.prompt) + len(r.out) - 1
-            tokens[i, 0] = r.out[-1]
-            tokens[i, 1 : 1 + len(di)] = di
-            lengths[i] = 1 + len(di)
-            if self.paged:
-                self._cow_guard(i, r, int(base[i]), 1 + len(di))
-        self._sync_table()
-
+                base_rows = len(r.prompt) + len(r.out)  # width-1 write
+                if self.paged and kk > 0:
+                    if not self._ensure_rows(i, r, base_rows + kk,
+                                             preempt=False):
+                        # pool pressure: speculative HEADROOM is not
+                        # worth preempting a neighbour — fall back to
+                        # plain decode for this round
+                        kk = 0
+                        self.spec.degraded_rounds += 1
+                if self.paged and not self._ensure_rows(i, r,
+                                                        base_rows + kk):
+                    continue  # preempted or blocked: sits this round out
+                kks[i] = kk
+            rows = [(i, r) for i, r in rows
+                    if self.active[i] is r and i in kks]
+            if not rows:
+                return None
+            jobs = []
+            for i, r in rows:
+                if kks[i] > 0:
+                    jobs.append((
+                        i,
+                        np.concatenate([r.prompt,
+                                        np.asarray(r.out, np.int32)]),
+                        kks[i],
+                    ))
+            drafts: dict[int, list[int]] = {i: [] for i, _ in rows}
+            qdists: dict[int, np.ndarray] = {}
+            if jobs:
+                d, q = self.drafter.draft_round(
+                    jobs, sampling=self.sampling,
+                    rngs={i: self.active[i].rng for i, _, _ in jobs},
+                )
+                drafts.update(d)
+                qdists.update(q)
+            # one verify forward scores every row's chunk at once
+            width = self.speculate + 1
+            tokens = np.zeros((self.slots, width), np.int32)
+            lengths = np.zeros((self.slots,), np.int32)
+            base = np.zeros((self.slots,), np.int32)
+            for i, r in rows:
+                di = drafts[i]
+                base[i] = len(r.prompt) + len(r.out) - 1
+                tokens[i, 0] = r.out[-1]
+                tokens[i, 1 : 1 + len(di)] = di
+                lengths[i] = 1 + len(di)
+                if self.paged:
+                    self._cow_guard(i, r, int(base[i]), 1 + len(di))
+            self._sync_table()
+            if span.live:
+                span.note(rids=[r.rid for _, r in rows],
+                          positions=[int(base[i]) for i, _ in rows])
         probe_now = self._probe_due()
-
-        def _score():
-            return self.verifier.score(self.cache, tokens, lengths,
-                                       greedy=greedy,
-                                       keep_logits0=probe_now)
-
-        t0 = _now()
-        scores, self.cache, snap = self._call("verify", _score)
-        t1 = _now()
-        self._tl("verify", rows=len(rows), drafting=len(jobs),
-                 k=self.speculate,
-                 degraded=self.spec.degraded_rounds - deg0)
+        with span.phase("dispatch"):
+            logits, self.cache, snap = self._call(
+                "verify", lambda: self.verifier.forward(
+                    self.cache, tokens, lengths))
+        with span.phase("fetch"):
+            scores = self.verifier.fetch(logits, greedy=greedy,
+                                         keep_logits0=probe_now)
+        rec = self._tl("verify", rows=len(rows), drafting=len(jobs),
+                       k=self.speculate,
+                       degraded=self.spec.degraded_rounds - deg0)
         if probe_now:
             # position 0 of the verify chunk is the target distribution
             # after the last emitted token — the same quantity step()
@@ -1486,66 +1564,70 @@ class BatchedServer:
             logits0 = self.verifier.last_logits0
             for i, r in rows:
                 self._probe_row(r, logits0[i])
-        self.spec.rounds += 1
-        self.spec.target_forwards += 1
-        # host-side acceptance per request, then one batched rollback
-        new_lens = base + lengths  # post-verify lens
-        rejected = np.zeros((self.slots,), bool)
-        verdicts: dict[int, int] = {}
-        emits: dict[int, int] = {}
-        for i, r in rows:
-            di = drafts[i]
-            if greedy:
-                m, tok = accept_greedy(di, scores[i])
-            else:
-                p = np.stack([
-                    shaped_probs(scores[i, j], **self.sampling)
-                    for j in range(len(di) + 1)
-                ])
-                m, tok = accept_speculative(di, qdists.get(i), p, r.rng)
-            self.spec.drafted += len(di)
-            self.spec.accepted += m
-            if len(di) and self.registry.enabled:
-                self.registry.counter(
-                    "spec_drafted_total", "draft tokens proposed",
-                ).inc(len(di), replica=self._rep(i))
-                if m:
+        with span.phase("emit"):
+            t0, t1 = self._round_ends(span)
+            self.spec.rounds += 1
+            self.spec.target_forwards += 1
+            # host-side acceptance per request, then one batched rollback
+            new_lens = base + lengths  # post-verify lens
+            rejected = np.zeros((self.slots,), bool)
+            verdicts: dict[int, int] = {}
+            emits: dict[int, int] = {}
+            for i, r in rows:
+                di = drafts[i]
+                if greedy:
+                    m, tok = accept_greedy(di, scores[i])
+                else:
+                    p = np.stack([
+                        shaped_probs(scores[i, j], **self.sampling)
+                        for j in range(len(di) + 1)
+                    ])
+                    m, tok = accept_speculative(di, qdists.get(i), p,
+                                                r.rng)
+                self.spec.drafted += len(di)
+                self.spec.accepted += m
+                if len(di) and self.registry.enabled:
                     self.registry.counter(
-                        "spec_accepted_total",
-                        "draft tokens that survived verification",
-                    ).inc(m, replica=self._rep(i))
-            if r.acc is not None and len(di):
-                r.acc.record(len(di), m)
-            if kks[i] > 0:
-                verdicts[i] = m
-            if m < len(di):  # rejected suffix: un-write it
-                rejected[i] = True
-                new_lens[i] = base[i] + m + 1
-            emits[i] = tok
-        if rejected.any():
-            self.cache = self.verifier.rollback(
-                self.cache, snap, base, new_lens, rejected, tokens
-            )
-            if self._recurrent:
-                self.spec.recompute_forwards += 1
-                self.spec.target_forwards += 1
-        if verdicts:
-            self.drafter.finish_round(verdicts)
-        for i, r in rows:
-            before = len(r.out)
-            for t in drafts[i][: verdicts.get(i, 0)]:
-                self._emit(r, t)
+                        "spec_drafted_total", "draft tokens proposed",
+                    ).inc(len(di), replica=self._rep(i))
+                    if m:
+                        self.registry.counter(
+                            "spec_accepted_total",
+                            "draft tokens that survived verification",
+                        ).inc(m, replica=self._rep(i))
+                if r.acc is not None and len(di):
+                    r.acc.record(len(di), m)
+                if kks[i] > 0:
+                    verdicts[i] = m
+                if m < len(di):  # rejected suffix: un-write it
+                    rejected[i] = True
+                    new_lens[i] = base[i] + m + 1
+                emits[i] = tok
+            if rejected.any():
+                self.cache = self.verifier.rollback(
+                    self.cache, snap, base, new_lens, rejected, tokens
+                )
+                if self._recurrent:
+                    self.spec.recompute_forwards += 1
+                    self.spec.target_forwards += 1
+            if verdicts:
+                self.drafter.finish_round(verdicts)
+            for i, r in rows:
+                before = len(r.out)
+                for t in drafts[i][: verdicts.get(i, 0)]:
+                    self._emit(r, t)
+                    self.spec.emitted += 1
+                self._emit(r, emits[i])
                 self.spec.emitted += 1
-            self._emit(r, emits[i])
-            self.spec.emitted += 1
-            self._span(i, r, "verify", t0, t1, before,
-                       drafted=len(drafts[i]),
-                       accepted=verdicts.get(i, 0))
-            if r.draft_on and r.max_new - len(r.out) - 1 <= 0:
-                # out of draft budget: the drafter is done with this slot
-                # one round before the target retires — release its pages
-                self.drafter.release(i)
-        return True
+                self._span(i, r, "verify", t0, t1, before,
+                           drafted=len(drafts[i]),
+                           accepted=verdicts.get(i, 0))
+                if r.draft_on and r.max_new - len(r.out) - 1 <= 0:
+                    # out of draft budget: the drafter is done with this
+                    # slot one round before the target retires — release
+                    # its pages
+                    self.drafter.release(i)
+        return rec
 
     def _stall(self) -> SchedulerStall:
         """Build the diagnostic stall exception from live-slot state."""
@@ -1637,7 +1719,13 @@ class BatchedServer:
         every scheduler iteration for newly admitted requests (the asyncio
         front-end hands it ``FairScheduler.drain``), and an idle server
         waits ``idle_wait_s`` instead of exiting — the run then ends only
-        through the drain path (SIGTERM guard or ``max_wall_s``)."""
+        through the drain path (SIGTERM guard or ``max_wall_s``).
+
+        With spans on (``Observability.span``, live with the tracer) the
+        loop top is named in a profiler trace too: ``host.serve.feed``,
+        ``host.serve.retire``, ``host.serve.admit`` and ``host.serve.idle``
+        go to the profiler only, never to the timeline (an idle service
+        loop polls every ``idle_wait_s``)."""
         if self.speculate and any(r.force is not None for r in requests):
             raise ValueError(
                 "teacher forcing (Request.force) is incompatible with "
@@ -1660,17 +1748,19 @@ class BatchedServer:
                 if self.profile is not None:
                     self.profile.on_tick(steps)
                 if feed is not None:
-                    for r in feed():
-                        self._pending.append(r)
-                        self.tracer.queued(r.rid, r.queued_t)
+                    with self.obs.span("host.serve.feed"):
+                        for r in feed():
+                            self._pending.append(r)
+                            self.tracer.queued(r.rid, r.queued_t)
                 if self._drain_due(t0):
                     self._drain(done)
                     break
                 # retire finished slots — including requests whose single
                 # token came straight from the previous prefill wave
-                for i, r in enumerate(self.active):
-                    if r is not None and r.done:
-                        self._retire(i, r, done)
+                with self.obs.span("host.serve.retire"):
+                    for i, r in enumerate(self.active):
+                        if r is not None and r.done:
+                            self._retire(i, r, done)
                 if self._pending and any(s is None for s in self.active):
                     if self._fill_slots(self._pending):
                         self.peak_concurrency = max(
@@ -1701,7 +1791,8 @@ class BatchedServer:
                 if feed is not None:
                     # service mode: idle is not done — wait for traffic
                     # until the guard/wall-clock drain says stop
-                    time.sleep(idle_wait_s)
+                    with self.obs.span("host.serve.idle"):
+                        time.sleep(idle_wait_s)
                     continue
                 break
         finally:

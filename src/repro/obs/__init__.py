@@ -8,13 +8,16 @@ surfaces threaded through ``launch/serve.py`` and friends:
 * ``tracer`` (:mod:`repro.obs.trace`): per-request lifecycle spans
   (TTFT, TPOT, queue wait, preemption/replay overhead);
 * ``timeline`` (:mod:`repro.obs.timeline`): the ring-buffered per-tick
-  scheduler event log that replaced ``BatchedServer.events``.
+  scheduler event log that replaced ``BatchedServer.events``;
+* ``span(name)`` (:mod:`repro.obs.span`): named spans of the serving
+  loop on the profiler's clock, on exactly when the tracer is live.
 
-``Observability.disabled()`` swaps registry and tracer for no-ops but
-keeps a REAL timeline: the ``server.events`` compat shim and the drop
-accounting must behave identically in both modes, and the bit-identity
-test (tests/test_obs.py) pins that enabled vs. disabled telemetry
-produces the same greedy streams and compile counts.
+``Observability.disabled()`` swaps registry and tracer for no-ops (and
+so turns spans off) but keeps a REAL timeline: the ``server.events``
+compat shim and the drop accounting must behave identically in both
+modes, and the bit-identity test (tests/test_obs.py) pins that enabled
+vs. disabled telemetry produces the same greedy streams and compile
+counts.
 """
 from __future__ import annotations
 
@@ -24,11 +27,12 @@ from .metrics import (DEFAULT_TIME_BUCKETS, NullRegistry, Registry,
 from .timeline import DEFAULT_CAP, Timeline, read_jsonl
 from .trace import NullTracer, Span, Tracer
 from .profile import JaxProfile, StepTimer, compile_counts, timeit
+from .span import NO_SPAN, LoopSpan
 
 __all__ = [
-    "DEFAULT_CAP", "DEFAULT_TIME_BUCKETS", "JaxProfile", "NullRegistry",
-    "NullTracer", "Observability", "Registry", "Span", "StepTimer",
-    "Timeline", "Tracer", "compile_counts", "global_registry",
+    "DEFAULT_CAP", "DEFAULT_TIME_BUCKETS", "JaxProfile", "NO_SPAN",
+    "NullRegistry", "NullTracer", "Observability", "Registry", "Span",
+    "StepTimer", "Timeline", "Tracer", "compile_counts", "global_registry",
     "parse_prometheus", "read_jsonl", "reset_global_registry", "timeit",
 ]
 
@@ -48,6 +52,7 @@ class Observability:
         self.timeline = (timeline if timeline is not None
                          else Timeline(cap=trace_cap))
         self.step_timer = StepTimer(self.registry)
+        self._spans = self.tracer.enabled
 
     @classmethod
     def disabled(cls, *, trace_cap: int = DEFAULT_CAP) -> "Observability":
@@ -58,6 +63,14 @@ class Observability:
     @property
     def enabled(self) -> bool:
         return self.registry.enabled
+
+    def span(self, name: str, **fields):
+        """A named span of the serving loop (see :mod:`repro.obs.span`):
+        a profiler annotation with monotonic ends, live exactly when the
+        tracer is; otherwise the shared no-op :data:`NO_SPAN`."""
+        if not self._spans:
+            return NO_SPAN
+        return LoopSpan(name, fields)
 
     def dump_metrics(self, path) -> None:
         """Write the Prometheus-text snapshot (``--metrics-out``)."""

@@ -9,12 +9,11 @@ output (async dispatch excluded), MEDIAN of k iterations (one GC pause
 or interrupt can no longer crown the wrong block shape the way a mean
 could).
 
-``StepTimer`` wraps the serving loop's jitted seams (prefill / decode /
-verify / draft): it blocks on the step's output and files the host wall
-time into a per-seam histogram. Blocking is observational — jitted step
-values are unchanged — and the timer is only installed when the registry
-is live, so a ``NullRegistry`` run pays nothing (the bit-identity test
-pins both configurations to the same streams and compile counts).
+``StepTimer`` files the serving loop's step times (prefill / decode /
+verify) into a per-seam histogram. The server hands it each wave's or
+step's time from dispatch to tokens on the host, read off the loop's
+spans (``repro.obs.span``): nothing blocks on the device for it. It
+records only with a live registry, so a ``NullRegistry`` run pays nothing.
 
 ``compile_counts`` reads each jitted function's compilation-cache size in
 one place — the source for the ``decode_compiles``-style stats the tests
@@ -50,32 +49,25 @@ def timeit(f, *args, iters: int = 5, warmup: int = 1) -> float:
 
 
 class StepTimer:
-    """Per-seam wall timing for the serving loop's jitted device steps."""
+    """Per-seam wall timing of the serving loop's device steps."""
 
     def __init__(self, registry, name: str = "serve_step_seconds",
-                 help: str = "host wall seconds per jitted serving step "
-                             "(block_until_ready)"):
+                 help: str = "host wall seconds per serving step, from "
+                             "dispatch to tokens on the host"):
         self.registry = registry
         self.enabled = registry is not None and registry.enabled
         self._hist = registry.histogram(name, help) if self.enabled else None
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
 
-    def run(self, seam: str, fn):
-        """Execute ``fn()``; when live, block on its output and record the
-        wall time under ``seam``. Pass-through when disabled."""
+    def observe(self, seam: str, seconds: float) -> None:
+        """Record one step of ``seam`` that took ``seconds``; a no-op
+        when disabled."""
         if not self.enabled:
-            return fn()
-        import jax
-
-        t0 = time.perf_counter()
-        out = fn()
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        self._hist.observe(dt, seam=seam)
-        self.totals[seam] = self.totals.get(seam, 0.0) + dt
+            return
+        self._hist.observe(seconds, seam=seam)
+        self.totals[seam] = self.totals.get(seam, 0.0) + seconds
         self.counts[seam] = self.counts.get(seam, 0) + 1
-        return out
 
     def summary(self) -> dict:
         """Per-seam totals — the tick-time breakdown (where a decode

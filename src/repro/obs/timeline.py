@@ -53,7 +53,9 @@ class Timeline:
     def set_tick(self, tick: int) -> None:
         self.tick = tick
 
-    def emit(self, kind: str, **fields) -> None:
+    def emit(self, kind: str, **fields) -> dict:
+        """Append one record and return it: a caller may add fields it
+        learns later (a round's spans, once the round is over)."""
         rec = {"seq": self.seq, "tick": self.tick,
                "t": time.monotonic(), "kind": kind}
         rec.update(fields)
@@ -61,6 +63,7 @@ class Timeline:
             self.dropped += 1  # deque drops the oldest on append
         self._buf.append(rec)
         self.seq += 1
+        return rec
 
     # -- reads ---------------------------------------------------------------
 

@@ -84,7 +84,7 @@ class Verifier:
         self._cache_shd = cache_shd
         self.registry = registry  # optional obs registry (set by the server)
         self.last_logits0 = None  # host (B, V) position-0 logits rows, kept
-        #                           only when score() is asked (quality probe)
+        #                           only when fetch() is asked (quality probe)
 
         # private closure: jit caches are keyed by the wrapped function, so
         # wrapping model.verify_step directly would share a compile count
@@ -113,25 +113,13 @@ class Verifier:
     def compiles(self) -> int:
         return self._verify._cache_size()
 
-    def score(self, cache: dict, tokens: np.ndarray, lengths: np.ndarray,
-              greedy: bool = False, keep_logits0: bool = False):
-        """Run the verify forward. Returns ``(scores, new_cache,
-        snapshot)`` — the snapshot holds the pre-verify recurrent leaves
-        for :meth:`rollback` (empty for attention-only families).
-
-        ``scores`` is the full ``(B, S, V)`` logits host array for
-        sampling, but greedy acceptance only compares token ids: with
-        ``greedy`` the argmax runs ON DEVICE and only ``(B, S)`` ints
-        cross to the host — the verify-wave analogue of the serve path's
-        device-argmax decode (full-vocab rows at production V would
-        otherwise dominate the round).
-
-        ``keep_logits0`` stashes the position-0 logits rows (the target
-        distribution after the last emitted token) on
-        ``self.last_logits0`` for the serve path's quality probe — a
-        host transfer off the already-computed forward, never an extra
-        trace or device call, so greedy streams and compile counts are
-        untouched."""
+    def forward(self, cache: dict, tokens: np.ndarray,
+                lengths: np.ndarray):
+        """Dispatch the verify forward. Returns ``(logits, new_cache,
+        snapshot)``: the ``(B, S, V)`` logits stay on the device (see
+        :meth:`fetch`), and the snapshot holds the pre-verify recurrent
+        leaves for :meth:`rollback` (empty for attention-only
+        families)."""
         snap = {k: cache[k] for k in self._recurrent}
         logits, cache = self._verify(
             self.params, self._put(tokens), self._put(lengths), cache
@@ -141,10 +129,27 @@ class Verifier:
                 "spec_verify_forwards_total",
                 "target-model verify forwards (incl. rollback recompute)",
             ).inc()
+        return logits, cache, snap
+
+    def fetch(self, logits, greedy: bool = False,
+              keep_logits0: bool = False) -> np.ndarray:
+        """The verify scores on the host.
+
+        The full ``(B, S, V)`` logits for sampling, but greedy acceptance
+        only compares token ids: with ``greedy`` the argmax runs ON DEVICE
+        and only ``(B, S)`` ints cross to the host — the verify-wave
+        analogue of the serve path's device-argmax decode (full-vocab rows
+        at production V would otherwise dominate the round).
+
+        ``keep_logits0`` stashes the position-0 logits rows (the target
+        distribution after the last emitted token) on
+        ``self.last_logits0`` for the serve path's quality probe — a
+        host transfer off the already-computed forward, never an extra
+        trace or device call, so greedy streams and compile counts are
+        untouched."""
         if keep_logits0:
             self.last_logits0 = np.asarray(logits[:, 0])
-        scores = np.asarray(jnp.argmax(logits, -1) if greedy else logits)
-        return scores, cache, snap
+        return np.asarray(jnp.argmax(logits, -1) if greedy else logits)
 
     def rollback(
         self,
