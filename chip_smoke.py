@@ -217,12 +217,13 @@ def paged_phase(cfg) -> None:
     rng = np.random.default_rng(3)
     dt = jnp.dtype(cfg.dtype)
     q = jnp.asarray(rng.normal(size=(BATCH, kv, g, hd)), dt)
-    kp = jnp.asarray(rng.normal(size=(pool, PAGE, kv, hd)), dt)
-    vp = jnp.asarray(rng.normal(size=(pool, PAGE, kv, hd)), dt)
+    # two layers' pools; the kernel reads layer 1 of the whole pool
+    pages = jnp.asarray(rng.normal(size=(2, 2, pool, PAGE, kv, hd)), dt)
     table = jnp.asarray(rng.permutation(pool).reshape(BATCH, n_row), jnp.int32)
     lengths = jnp.asarray([0, 37, 300, n_row * PAGE][:BATCH], jnp.int32)
-    got = jax.jit(paged_attention)(q, kp, vp, table, lengths)
-    want = jax.jit(paged_attention_reference)(q, kp, vp, table, lengths)
+    got = jax.jit(paged_attention)(q, pages, 1, table, lengths)
+    want = jax.jit(paged_attention_reference)(q, pages[1, 0], pages[1, 1],
+                                              table, lengths)
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)
     err = float(np.abs(got - want).max())

@@ -262,23 +262,29 @@ def _splitq_packed_group_matmul(x, grp, *, block):
 
 def paged_attention(
     q: jax.Array,           # (B, KV, G, hd)
-    k_pages: jax.Array,     # (P, page, KV, hd)
-    v_pages: jax.Array,     # (P, page, KV, hd)
+    pages: jax.Array,       # (L, 2, P, page, KV, hd)
+    layer: jax.Array | int,
     page_table: jax.Array,  # (B, NP)
     lengths: jax.Array,     # (B,)
     window: jax.Array | int = 0,
     chunk: jax.Array | int = 0,
 ) -> jax.Array:
-    """Paged decode attention (``kernels.paged_attention``) over a page
-    pool; the pools are gathered whole on a serving mesh, so page ids stay
-    global."""
-    def run(q, k_pages, v_pages, page_table, lengths, window, chunk):
+    """Paged decode attention (``kernels.paged_attention``) over layer
+    ``layer`` of the whole page pool, read in place. On a serving mesh the
+    pool is gathered whole for the call (page ids stay global), so the
+    layer's pool is cut out first: the call gathers one layer, not all."""
+    layer = jnp.asarray(layer, jnp.int32)
+    if serving_mesh() is not None:
+        pages = jax.lax.dynamic_index_in_dim(pages, layer, 0, keepdims=True)
+        layer = jnp.int32(0)
+
+    def run(q, pages, layer, page_table, lengths, window, chunk):
         return paged_mod.paged_attention_pallas(
-            q, k_pages, v_pages, page_table, lengths, window=window,
+            q, pages, layer, page_table, lengths, window=window,
             chunk=chunk, interpret=interpret_mode())
 
-    return _on_mesh(run, (0, 3, 4), q, k_pages, v_pages, page_table,
-                    lengths, jnp.asarray(window, jnp.int32),
+    return _on_mesh(run, (0, 3, 4), q, pages, layer, page_table, lengths,
+                    jnp.asarray(window, jnp.int32),
                     jnp.asarray(chunk, jnp.int32))
 
 

@@ -2,11 +2,15 @@
 
 Decode attention over a PAGED KV cache: instead of indexing one contiguous
 ``(B, Smax, KV, hd)`` strip, each batch row follows its page-table row
-through a shared pool of fixed-size pages. The kernel uses
-``PrefetchScalarGridSpec``: the page table and per-row lengths are scalar-
-prefetched so the K/V BlockSpec index maps can resolve ``logical page i of
-row b`` -> physical page id BEFORE the body runs — K/V never need to be
-gathered into a contiguous per-row strip in HBM (the XLA reference path
+through a shared pool of fixed-size pages. The kernel takes the WHOLE
+pool of every layer, ``(L, 2, P, page, KV, hd)``, and the layer to read:
+the layer scan carries that pool and updates it in place, so cutting a
+layer (or a K/V plane) out for the kernel would copy it once per layer.
+It uses ``PrefetchScalarGridSpec``: the page table, the per-row lengths
+and ``[window, chunk, layer]`` are scalar-prefetched so the K/V BlockSpec
+index maps can resolve ``logical page i of row b`` -> ``(layer, plane,
+physical page)`` BEFORE the body runs — K/V never need to be gathered
+into a contiguous per-row strip in HBM (the XLA reference path
 materialises exactly that gather). The grid still sweeps every logical
 page slot per row, so fetch traffic is O(table width), not O(len): dead
 slots re-fetch a clamped page and are masked in the body. Skipping them
@@ -30,7 +34,8 @@ as the reference softmax guard in ``models/attention.py``, not a uniform
 average over garbage.
 
 ``paged_attention_reference`` is the pure-jnp oracle (gather + masked
-softmax) used for CPU CI and the kernel-equivalence test.
+softmax) over ONE layer's K and V planes, used for CPU CI and the
+kernel-equivalence test.
 """
 from __future__ import annotations
 
@@ -47,7 +52,8 @@ NEG_INF = -1e30
 def _paged_kernel(
     pt_ref,    # (B, NP) scalar-prefetch: physical page ids
     len_ref,   # (B,)    scalar-prefetch: valid KV length per row
-    meta_ref,  # (2,)    scalar-prefetch: [window, chunk] (0 => disabled)
+    meta_ref,  # (3,)    scalar-prefetch: [window, chunk, layer]; window or
+               #         chunk 0 => that mask is off
     q_ref,     # (1, KV*G, hd)
     k_ref,     # (1, page, KV, hd) — physical page selected by index_map
     v_ref,     # (1, page, KV, hd)
@@ -108,8 +114,8 @@ def _paged_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(
     q: jax.Array,           # (B, KV, G, hd)
-    k_pages: jax.Array,     # (P, page, KV, hd)
-    v_pages: jax.Array,     # (P, page, KV, hd)
+    pages: jax.Array,       # (L, 2, P, page, KV, hd) every layer's pool
+    layer: jax.Array | int,  # the layer whose K/V planes are read
     page_table: jax.Array,  # (B, NP) int32
     lengths: jax.Array,     # (B,) int32 valid KV length (post-write)
     window: jax.Array | int = 0,
@@ -117,11 +123,12 @@ def paged_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     b, kvh, g, hd = q.shape
-    p_total, page = k_pages.shape[0], k_pages.shape[1]
+    p_total, page = pages.shape[2], pages.shape[3]
     n_pages = page_table.shape[1]
     rows, cols = kvh * g, page * kvh
     meta = jnp.stack([jnp.asarray(window, jnp.int32).reshape(()),
-                      jnp.asarray(chunk, jnp.int32).reshape(())])
+                      jnp.asarray(chunk, jnp.int32).reshape(()),
+                      jnp.asarray(layer, jnp.int32).reshape(())])
     # key row p*KV + h of a page is (position p, kv head h); query row
     # h*G + g belongs to kv head h (kv-major head split)
     key_head = jnp.arange(cols, dtype=jnp.int32) % kvh
@@ -129,10 +136,13 @@ def paged_attention_pallas(
             == key_head[None, :]).astype(jnp.int32)
     off = (jnp.arange(cols, dtype=jnp.int32) // kvh)[None, :]
 
-    def kv_map(bb, i, pt, ln, mt):
-        # stale table entries past a row's live pages still index SOME real
-        # page; their contributions are masked by len in the body
-        return (jnp.clip(pt[bb, i], 0, p_total - 1), 0, 0, 0)
+    def kv_map(plane):
+        def index(bb, i, pt, ln, mt):
+            # stale table entries past a row's live pages still index SOME
+            # real page; their contributions are masked by len in the body
+            return (mt[2], plane, jnp.clip(pt[bb, i], 0, p_total - 1),
+                    0, 0, 0)
+        return index
 
     def fixed(bb, i, pt, ln, mt):
         return (0, 0)
@@ -145,8 +155,9 @@ def paged_attention_pallas(
         grid=(b, n_pages),
         in_specs=[
             pl.BlockSpec((1, rows, hd), row_map),
-            pl.BlockSpec((1, page, kvh, hd), kv_map),
-            pl.BlockSpec((1, page, kvh, hd), kv_map),
+            # layer and plane squeezed: the body sees (1, page, KV, hd)
+            pl.BlockSpec((None, None, 1, page, kvh, hd), kv_map(0)),
+            pl.BlockSpec((None, None, 1, page, kvh, hd), kv_map(1)),
             pl.BlockSpec((rows, cols), fixed),
             pl.BlockSpec((1, cols), fixed),
         ],
@@ -168,7 +179,7 @@ def paged_attention_pallas(
         interpret=interpret,
     )(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), meta,
-        q.reshape(b, rows, hd), k_pages, v_pages, head, off,
+        q.reshape(b, rows, hd), pages, pages, head, off,
     )
     return out.reshape(q.shape)
 
@@ -189,7 +200,7 @@ def paged_attention_reference(
     page = k_pages.shape[1]
     n_pages = page_table.shape[1]
     # one source of truth for the page addressing math
-    kl, vl = logical_view(jnp.stack([k_pages, v_pages]), page_table)
+    kl, vl = logical_view(jnp.stack([k_pages, v_pages])[None], 0, page_table)
     s_log = n_pages * page
     k_pos = jnp.arange(s_log, dtype=jnp.int32)[None]          # (1, S_log)
     q_pos = (lengths.astype(jnp.int32) - 1)[:, None]          # (B, 1)

@@ -1,12 +1,19 @@
 """Paged KV-cache layout math (device side).
 
-Layout: one pool of ``P`` fixed-size pages per layer, ``kv_pages`` shaped
-``(2, P, page, KV, hd)`` (the leading 2 is K/V). A request's logical token
-position ``t`` lives in logical page ``t // page`` at offset ``t % page``;
-the per-slot ``page_table`` row maps logical page index -> physical page
-id, so the flat physical index is::
+Layout: one pool of ``P`` fixed-size pages per layer, every layer in one
+array ``pages`` shaped ``(L, 2, P, page, KV, hd)`` (the 2 is K/V). A
+request's logical token position ``t`` lives in logical page ``t // page``
+at offset ``t % page``; the per-slot ``page_table`` row maps logical page
+index -> physical page id, so the flat physical index within one layer's
+K or V plane is::
 
     phys(b, t) = page_table[b, t // page] * page + t % page
+
+Inside a step the whole pool is what moves: the layer scan carries it,
+each layer scatters into its own planes (:func:`paged_write`) and reads
+them (:func:`logical_view`, the paged-attention kernel) by layer index,
+and the serving programs take it donated, so a step updates the pool in
+place and never copies it.
 
 Logical position == absolute token position, which is what keeps RoPE,
 causal/sliding-window/chunked masks and the per-slot ``len`` contract
@@ -109,23 +116,36 @@ def write_pages(pool: jax.Array, ids, values) -> jax.Array:
     return pool.at[:, :, idx].set(jnp.asarray(values, pool.dtype))
 
 
+def _plane_base(pages: jax.Array, layer) -> tuple[jax.Array, int]:
+    """Flat row index of layer ``layer``'s K plane in ``pages`` viewed as
+    ``(L*2*P*page, KV, hd)``, and the rows one plane spans (its V plane
+    starts that many rows later)."""
+    p_total, page = pages.shape[2], pages.shape[3]
+    plane_n = p_total * page
+    return jnp.asarray(layer, jnp.int32) * (2 * plane_n), plane_n
+
+
 def paged_write(
-    kv_pages: jax.Array,   # (2, P, page, KV, hd)
-    k: jax.Array,          # (B, S, KV, hd)
-    v: jax.Array,          # (B, S, KV, hd)
+    pages: jax.Array,       # (L, 2, P, page, KV, hd)
+    layer: jax.Array | int,  # which layer's planes to write
+    k: jax.Array,           # (B, S, KV, hd)
+    v: jax.Array,           # (B, S, KV, hd)
     page_table: jax.Array,  # (B, NP) int32 physical page ids
-    starts: jax.Array,     # (B,) logical write offset per row
+    starts: jax.Array,      # (B,) logical write offset per row
     seq_lens: jax.Array | None = None,  # (B,) valid new tokens (None => S)
 ) -> jax.Array:
-    """Scatter new K/V tokens into their physical page slots.
+    """Scatter new K/V tokens into layer ``layer``'s physical page slots
+    of the whole pool; returns the whole pool.
 
     Row ``b`` writes its first ``seq_lens[b]`` tokens at logical positions
     ``starts[b] + j``; invalid positions (frozen rows, right-padding,
     out-of-table) map to an out-of-bounds flat index and are DROPPED by the
     scatter — the paged equivalent of the dense path's per-row masked
-    ``dynamic_update_slice``. O(B*S) work: the pool is never traversed.
+    ``dynamic_update_slice``. O(B*S) work: one scatter addressed by layer,
+    plane and flat slot, so no layer's pool is cut out or rebuilt and a
+    pool carried through the layer scan is updated in place.
     """
-    _, p_total, page, kvh, hd = kv_pages.shape
+    n_layers, _, _, page, kvh, hd = pages.shape
     b, s = k.shape[0], k.shape[1]
     np_max = page_table.shape[1]
     t = starts.astype(jnp.int32)[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
@@ -134,23 +154,25 @@ def paged_write(
         valid &= jnp.arange(s, dtype=jnp.int32)[None] < seq_lens.astype(jnp.int32)[:, None]
     logical = jnp.clip(t // page, 0, np_max - 1)
     phys_page = jnp.take_along_axis(page_table.astype(jnp.int32), logical, axis=1)
-    flat_n = p_total * page
-    phys = jnp.where(valid, phys_page * page + t % page, flat_n)  # OOB => drop
-    idx = phys.reshape(b * s)
-    kc = kv_pages[0].reshape(flat_n, kvh, hd).at[idx].set(
-        k.astype(kv_pages.dtype).reshape(b * s, kvh, hd), mode="drop"
-    )
-    vc = kv_pages[1].reshape(flat_n, kvh, hd).at[idx].set(
-        v.astype(kv_pages.dtype).reshape(b * s, kvh, hd), mode="drop"
-    )
-    return jnp.stack([kc, vc]).reshape(kv_pages.shape)
+    base, plane_n = _plane_base(pages, layer)
+    flat_n = n_layers * 2 * plane_n
+    k_slot = base + phys_page * page + t % page
+    # OOB => drop; the V plane's slots sit one plane past the K plane's
+    idx = jnp.where(valid[None], jnp.stack([k_slot, k_slot + plane_n]),
+                    flat_n).reshape(2 * b * s)
+    new = jnp.stack([k, v]).astype(pages.dtype).reshape(2 * b * s, kvh, hd)
+    flat = pages.reshape(flat_n, kvh, hd).at[idx].set(new, mode="drop")
+    return flat.reshape(pages.shape)
 
 
 def logical_view(
-    kv_pages: jax.Array,    # (2, P, page, KV, hd)
-    page_table: jax.Array,  # (B, NP)
+    pages: jax.Array,        # (L, 2, P, page, KV, hd)
+    layer: jax.Array | int,
+    page_table: jax.Array,   # (B, NP)
 ) -> tuple[jax.Array, jax.Array]:
-    """Gather each row's logical K/V strip ``(B, NP*page, KV, hd)``.
+    """Gather each row's logical K/V strip ``(B, NP*page, KV, hd)`` of
+    layer ``layer``, straight from the whole pool (the layer is folded
+    into the flat index, so no layer slice is materialised).
 
     This is the interpret-mode / XLA reference data path: the gathered
     strip feeds the exact same attention math as the contiguous cache
@@ -158,10 +180,11 @@ def logical_view(
     decoding are bit-identical. On TPU the paged-attention kernel reads
     pages directly in VMEM instead of materialising this gather in HBM.
     """
-    _, p_total, page, _, _ = kv_pages.shape
-    flat = kv_pages.reshape(2, p_total * page, *kv_pages.shape[3:])
+    p_total, page = pages.shape[2], pages.shape[3]
+    flat = pages.reshape(-1, *pages.shape[4:])
     pt = jnp.clip(page_table.astype(jnp.int32), 0, p_total - 1)
     phys = (pt[:, :, None] * page
             + jnp.arange(page, dtype=jnp.int32)[None, None, :])
     phys = phys.reshape(page_table.shape[0], -1)  # (B, NP*page)
-    return flat[0][phys], flat[1][phys]
+    base, plane_n = _plane_base(pages, layer)
+    return flat[base + phys], flat[base + plane_n + phys]

@@ -179,6 +179,40 @@ def _bucket(n: int, minimum: int) -> int:
 PROBE_BUCKETS = tuple(10.0 ** e for e in range(-8, 3))
 
 
+class _InPlaceStep:
+    """A serving step program that updates the cache in place.
+
+    ``in_place(*args, **kw)`` runs the jitted program with the cache
+    (positional argument ``cache_arg``) DONATED: the page pool, carried
+    through the layer scan, comes back in the buffer it went in, and the
+    cache handed in is consumed. Calling the step itself leaves the
+    caller's cache intact: it donates a copy. ``BatchedServer`` owns its
+    cache and runs ``in_place``; a step put in the program's place (an
+    instrumented step, a planted fault) calls the program plainly and may
+    keep, or hand back, the cache it was given."""
+
+    def __init__(self, fn: Callable, cache_arg: int, **jit_kw):
+        self.cache_arg = cache_arg
+        self.in_place = jax.jit(fn, donate_argnums=(cache_arg,), **jit_kw)
+
+    def __call__(self, *args, **kw):
+        args = list(args)
+        args[self.cache_arg] = jax.tree.map(jnp.copy, args[self.cache_arg])
+        return self.in_place(*args, **kw)
+
+    def lower(self, *args, **kw):
+        return self.in_place.lower(*args, **kw)
+
+    def _cache_size(self) -> int:
+        return self.in_place._cache_size()
+
+
+def _in_place(step: Callable) -> Callable:
+    """How the server runs a step on its own cache: an ``_InPlaceStep``
+    in place, any other step function as it is."""
+    return getattr(step, "in_place", step)
+
+
 class BatchedServer:
     """Fixed-slot continuous batching over a decode_step function.
 
@@ -265,6 +299,8 @@ class BatchedServer:
         self.profile = profile
         self.prefill_tokens = 0     # tokens actually fed through prefill
         self.pages_allocated = 0    # fresh pages allocated (incl. COW copies)
+        self.pool_rounds = 0        # decode steps and prefill waves, paged
+        self.pool_in_place = 0      # ... whose output pool is the input's buffer
         self.prefix_deferrals = 0   # admissions held back for cross-wave dedup
         # -- resilience (see module docstring + runtime.resilience) ---------
         self.page_growth = page_growth
@@ -418,9 +454,14 @@ class BatchedServer:
             self.verifier = None
             self.spec = None
 
+        # the decode and prefill programs take the cache DONATED
+        # (``_InPlaceStep``): stats()["kv"] counts the rounds whose pool
+        # came back in place. Nothing else holds the serving cache across
+        # a call: snapshots are host copies, and the verifier's, drafter's
+        # and probe's programs keep their own jits, without donation.
         plan = self._plan
         if plan is None:
-            self._decode = jax.jit(model.decode_step)
+            self._decode = _InPlaceStep(model.decode_step, 2)
         else:
             # the hints context is entered INSIDE the traced body: the
             # exact-TP act_constraints (and the kernels' shard_map over the
@@ -432,8 +473,8 @@ class BatchedServer:
                     return model.decode_step(params, tokens, cache,
                                              active=active)
 
-            self._decode = jax.jit(decode_step,
-                                   out_shardings=(None, self._cache_shd))
+            self._decode = _InPlaceStep(
+                decode_step, 2, out_shardings=(None, self._cache_shd))
 
         def _prefill_fn(params, tokens, lengths, fresh, starts, cache):
             # fresh rows restart at ``starts`` (0, or past a shared prefix)
@@ -448,10 +489,10 @@ class BatchedServer:
             )
 
         if plan is None:
-            self._prefill = jax.jit(_prefill_fn)
+            self._prefill = _InPlaceStep(_prefill_fn, 5)
         else:
-            self._prefill = jax.jit(_prefill_fn,
-                                    out_shardings=(None, self._cache_shd))
+            self._prefill = _InPlaceStep(
+                _prefill_fn, 5, out_shardings=(None, self._cache_shd))
 
         # -- quality observability (see module docstring) --------------------
         # capture_logits: the eval path asks for the host logits row behind
@@ -679,6 +720,25 @@ class BatchedServer:
             return jnp.asarray(arr)
         return self._plan.put_batch(arr)
 
+    def _pool_buffers(self) -> tuple[int, ...] | None:
+        """Device addresses of the paged KV pool's buffers, one per shard
+        (None without a pool). Read before a decode step's or prefill
+        wave's dispatch (the donated input is gone after it) and again
+        once its tokens are on the host, so nothing waits for it."""
+        pool = self.cache.get("pages", self.cache.get("shared_pages"))
+        if pool is None:
+            return None
+        return tuple(sh.data.unsafe_buffer_pointer()
+                     for sh in pool.addressable_shards)
+
+    def _count_pool_round(self, before: tuple[int, ...] | None) -> None:
+        """Count a paged round, and whether its program handed the pool
+        back in the buffer it was given (updated in place)."""
+        if before is None:
+            return
+        self.pool_rounds += 1
+        self.pool_in_place += self._pool_buffers() == before
+
     def _sync_table(self):
         """Re-upload the page table only when admission/retirement changed
         it — steady-state decode keeps the device copy (it rides through
@@ -711,11 +771,14 @@ class BatchedServer:
         """Run one device step through the fault boundary. With no
         injector installed this is a direct call (the hot path pays
         nothing). Under injection, the seam's slow/fail hooks fire first
-        and transient failures retry via ``run_with_retries`` — safe
-        because every step is a pure jitted function over an immutable
-        cache pytree (re-running cannot double-apply a write), with
+        and transient failures retry via ``run_with_retries``, with
         ``OutOfPages`` excluded (deterministic resource condition: the
-        scheduler's relief path owns it, not the retry loop)."""
+        scheduler's relief path owns it, not the retry loop). A retry is
+        safe because an injected fault fires BEFORE dispatch: the decode
+        and prefill programs consume their donated cache only once
+        dispatched, so a retried step sees the cache it was handed and
+        cannot double-apply a write. A program that fails after dispatch
+        has consumed that cache and cannot be re-run on it."""
         if self.inject is None:
             return fn()
 
@@ -1282,9 +1345,11 @@ class BatchedServer:
         with self.obs.span("host.serve.prefill") as sp:
             with sp.phase("prepare"):
                 lb, sizes, args = self._wave_arrays(rows, sp)
+                pool_in = self._pool_buffers()
             with sp.phase("dispatch"):
                 logits, self.cache = self._call(
-                    "prefill", lambda: self._prefill(self.params, *args))
+                    "prefill",
+                    lambda: _in_place(self._prefill)(self.params, *args))
             rec = self._tl("prefill", bucket=lb, rows=len(rows),
                            tokens=int(sum(sizes.values())))
             if self._snap_boundaries:
@@ -1298,6 +1363,7 @@ class BatchedServer:
                         }
             pick = self._pick_tokens(logits, sp)
             with sp.phase("emit"):
+                self._count_pool_round(pool_in)
                 t0, t1 = self._round_ends(sp)
                 for i, r in rows:
                     before = len(r.out)
@@ -1378,11 +1444,12 @@ class BatchedServer:
         with self.obs.span("host.serve.decode") as sp:
             with sp.phase("prepare"):
                 tokens, active = self._step_arrays(ready, sp)
+                pool_in = self._pool_buffers()
             if not active.any():
                 return False
             with sp.phase("dispatch"):
                 logits, self.cache = self._call(
-                    "decode", lambda: self._decode(
+                    "decode", lambda: _in_place(self._decode)(
                         self.params, self._put(tokens), self.cache,
                         active=self._put(active)))
             rec = self._tl("decode", rows=int(active.sum()))
@@ -1393,6 +1460,7 @@ class BatchedServer:
                         self._probe_row(r, rows_host[i])
             pick = self._pick_tokens(logits, sp)
             with sp.phase("emit"):
+                self._count_pool_round(pool_in)
                 t0, t1 = self._round_ends(sp)
                 for i, r in enumerate(self.active):
                     if active[i]:
@@ -1868,6 +1936,8 @@ class BatchedServer:
                 # those and must return the pool to zero in use)
                 "leaked": self.alloc.in_use - cached,
             }
+            stats["kv"] = {"pool_rounds": self.pool_rounds,
+                           "pool_in_place": self.pool_in_place}
             if self.prefixes is not None:
                 stats["prefix"] = self._prefix_stats()
                 stats["prefix"]["admission_deferrals"] = self.prefix_deferrals

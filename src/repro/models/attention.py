@@ -199,7 +199,8 @@ def attention_block(
     layer_window: jax.Array | int = 0,
     layer_chunk: jax.Array | int = 0,
     kv_cache: jax.Array | None = None,   # (2, B, Smax, KV, hd)
-    kv_pages: jax.Array | None = None,   # (2, P, page, KV, hd) paged pool
+    kv_pages: jax.Array | None = None,   # (L, 2, P, page, KV, hd) paged pool
+    kv_layer: jax.Array | int = 0,       # this layer's index into kv_pages
     page_table: jax.Array | None = None,  # (B, NP) with kv_pages
     cache_len: jax.Array | None = None,  # (B,) per-row fill (scalar ok)
     seq_lens: jax.Array | None = None,   # (B,) valid new tokens per row
@@ -218,7 +219,10 @@ def attention_block(
 
     Paged layout (``kv_pages`` + ``page_table`` instead of ``kv_cache``):
     identical contract over a shared page pool — writes scatter into each
-    row's physical pages and attention reads the row's logical view. Since
+    row's physical pages and attention reads the row's logical view.
+    ``kv_pages`` is every layer's pool and ``kv_layer`` this layer's index
+    into it; the whole pool comes back, updated in place (no layer or K/V
+    plane is cut out), so the layer scan can carry it. Since
     logical position == absolute position, RoPE and every mask are shared
     with the contiguous path. Decode on TPU dispatches to the Pallas
     paged-attention kernel; elsewhere (and for prefill) the logical gather
@@ -291,7 +295,8 @@ def attention_block(
         starts = jnp.broadcast_to(
             jnp.atleast_1d(cache_len), (b,)
         ).astype(jnp.int32)
-        new_cache = paged_write(kv_pages, k, v, page_table, starts, seq_lens)
+        new_cache = paged_write(kv_pages, kv_layer, k, v, page_table, starts,
+                                seq_lens)
         inc = s if seq_lens is None else seq_lens.astype(jnp.int32)
         k_len = starts + inc
         if s == 1 and _use_paged_kernel():
@@ -301,12 +306,12 @@ def attention_block(
             # decode reshape and the kernel's (B, KV, G, hd) layout
             qg = q[:, 0].reshape(b, kvh, h // kvh, hd)
             og = paged_attention(
-                qg, new_cache[0], new_cache[1], page_table, k_len,
+                qg, new_cache, kv_layer, page_table, k_len,
                 window=layer_window, chunk=layer_chunk,
             )
             out = og.reshape(b, 1, h, hd)
         else:
-            kl, vl = logical_view(new_cache, page_table)
+            kl, vl = logical_view(new_cache, kv_layer, page_table)
             s_log = kl.shape[1]
             k_pos = jnp.broadcast_to(jnp.arange(s_log)[None], (b, s_log))
             out = attend(

@@ -128,11 +128,12 @@ def layer_meta(cfg):
 
 
 def _shared_block(sp, cfg, x, pos, kv_slot=None, cache_len=None,
-                  seq_lens=None, page_table=None, paged=False):
+                  seq_lens=None, page_table=None, pages=None, slot=0):
+    """The zamba2 shared block; its KV is the dense ``kv_slot`` or slot
+    ``slot`` of the paged pool ``pages`` (the whole pool comes back)."""
     h, new_kv = attention_block(
         sp["attn"], cfg, rms_norm(x, sp["norm1_scale"], cfg.norm_eps), pos,
-        kv_cache=None if paged else kv_slot,
-        kv_pages=kv_slot if paged else None,
+        kv_cache=kv_slot, kv_pages=pages, kv_layer=slot,
         page_table=page_table,
         cache_len=cache_len, seq_lens=seq_lens,
     )
@@ -170,7 +171,11 @@ def decoder_forward(
     families) or ``shared_pages`` (zamba2 shared block) plus a
     ``page_table``, attention KV lives in a shared page pool addressed per
     row through the table; recurrent leaves (ssm/conv/wkv/shift) stay
-    dense — only positional KV benefits from paging.
+    dense — only positional KV benefits from paging. The whole pool rides
+    the scan CARRY, not the scanned inputs and outputs: each layer
+    scatters into and reads its own planes by layer index, so XLA updates
+    the loop state in place, and a caller that donates the cache gets its
+    pool back in the same buffer. Every other cache leaf is scanned.
     """
     if not remat_group:
         remat_group = getattr(cfg, "remat_group", 1)
@@ -182,7 +187,7 @@ def decoder_forward(
     every = cfg.shared_attn_every
 
     def block(x, layer_params, window, chunk, layer_cache, layer_cross, idx,
-              shared_kv):
+              shared_kv, pages):
         aux = jnp.float32(0.0)
         new_cache = layer_cache
         if cfg.family == "ssm":
@@ -223,12 +228,18 @@ def decoder_forward(
                     if skv is None:
                         y, _ = _shared_block(params["shared_attn"], cfg, xx, pos)
                         return y, skv
+                    if shared_paged:
+                        return _shared_block(
+                            params["shared_attn"], cfg, xx, pos,
+                            cache_len=cache_len, seq_lens=seq_lens,
+                            page_table=page_table, pages=skv, slot=slot,
+                        )
                     kv_slot = jax.lax.dynamic_index_in_dim(
                         skv, slot, keepdims=False
                     )
                     y, new_slot = _shared_block(
                         params["shared_attn"], cfg, xx, pos, kv_slot,
-                        cache_len, seq_lens, page_table, shared_paged,
+                        cache_len, seq_lens,
                     )
                     skv = jax.lax.dynamic_update_index_in_dim(
                         skv, new_slot.astype(skv.dtype), slot, 0
@@ -242,16 +253,13 @@ def decoder_forward(
                     (idx + 1) % every == 0, apply_shared, skip, (x, shared_kv)
                 )
         else:  # attention families
-            kv = pages = None
-            if layer_cache is not None:
-                kv = layer_cache.get("kv")
-                pages = layer_cache.get("pages")
+            kv = layer_cache.get("kv") if layer_cache is not None else None
             h, new_kv = attention_block(
                 layer_params["attn"], cfg,
                 rms_norm(x, layer_params["norm1_scale"], cfg.norm_eps), pos,
                 layer_window=window, layer_chunk=chunk,
-                kv_cache=kv, kv_pages=pages, page_table=page_table,
-                cache_len=cache_len, seq_lens=seq_lens,
+                kv_cache=kv, kv_pages=pages, kv_layer=idx,
+                page_table=page_table, cache_len=cache_len, seq_lens=seq_lens,
             )
             x = x + h
             if layer_cross is not None:
@@ -267,20 +275,23 @@ def decoder_forward(
             else:
                 h = mlp(layer_params["mlp"], h2, cfg.act, cfg.glu)
             x = x + h
-            if layer_cache is not None:
-                new_cache = ({"pages": new_kv} if pages is not None
-                             else {"kv": new_kv})
-        return x, new_cache, aux, shared_kv
+            if kv is not None:
+                new_cache = {"kv": new_kv}
+            elif pages is not None:
+                pages = new_kv
+        return x, new_cache, aux, shared_kv, pages
 
     idxs = jnp.arange(n_layers, dtype=jnp.int32)
     per_layer_cache = None
-    shared_kv0 = None
+    shared_kv0 = pages0 = None
     shared_paged = cache is not None and "shared_pages" in cache
     if cache is not None:
         per_layer_cache = {
             k: v for k, v in cache.items()
-            if k not in ("len", "shared_kv", "shared_pages", "page_table")
+            if k not in ("len", "shared_kv", "shared_pages", "page_table",
+                         "pages")
         }
+        pages0 = cache.get("pages")
         shared_kv0 = (cache["shared_pages"] if shared_paged
                       else cache.get("shared_kv"))
     cross = None
@@ -294,20 +305,20 @@ def decoder_forward(
         blk = jax.checkpoint(block) if remat else block
 
         def body(carry, scanned):
-            x, aux_tot, shared_kv = carry
+            x, aux_tot, shared_kv, pages = carry
             layer_params, window, chunk, layer_cache, layer_cross, idx = scanned
-            x, new_cache, aux, shared_kv = blk(
+            x, new_cache, aux, shared_kv, pages = blk(
                 x, layer_params, window, chunk, layer_cache, layer_cross,
-                idx, shared_kv,
+                idx, shared_kv, pages,
             )
             # SP: the scan-carried residual stream is the remat save point —
             # sequence-sharding it over `model` divides saved-activation
             # memory by the TP degree (no-op outside a mesh context).
             x = act_constraint(x, "residual")
-            return (x, aux_tot + aux, shared_kv), new_cache
+            return (x, aux_tot + aux, shared_kv, pages), new_cache
 
-        (x, aux_tot, shared_kv), new_layer_cache = jax.lax.scan(
-            body, (x, jnp.float32(0.0), shared_kv0),
+        (x, aux_tot, shared_kv, pages), new_layer_cache = jax.lax.scan(
+            body, (x, jnp.float32(0.0), shared_kv0, pages0),
             (layers, windows, chunks, per_layer_cache, cross, idxs),
         )
     else:
@@ -330,8 +341,9 @@ def decoder_forward(
                     lci = None
                     if lcross is not None:
                         lci = jax.tree.map(lambda a: a[i], lcross)
-                    x, _, aux, shared_kv = block(
-                        x, lpi, w[i], c[i], None, lci, idx[i], shared_kv
+                    x, _, aux, shared_kv, _ = block(
+                        x, lpi, w[i], c[i], None, lci, idx[i], shared_kv,
+                        None,
                     )
                 return x, aux_g + aux, shared_kv
 
@@ -353,6 +365,8 @@ def decoder_forward(
         else:
             inc = pos.shape[1] if pos.ndim >= 2 else 1
         new_cache["len"] = cache["len"] + inc
+        if pages0 is not None:
+            new_cache["pages"] = pages
         if shared_kv is not None:
             new_cache["shared_pages" if shared_paged else "shared_kv"] = (
                 shared_kv

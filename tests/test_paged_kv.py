@@ -13,7 +13,11 @@ The contract under test (the paged-serving tentpole):
   indirection,
 * fully-masked rows (``len == 0``) produce EXACT zeros from attention —
   the regression for the old ``k_len = max(k_len, 1)`` clamp that silently
-  attended one garbage key.
+  attended one garbage key,
+* the pool of every layer is read and written by layer index (kernel and
+  scatter touch their own layer only), and a server whose decode and
+  prefill programs take the cache donated hands the pool back in place
+  with its streams unchanged.
 """
 import dataclasses
 
@@ -27,7 +31,7 @@ from repro.kernels.paged_attention import (
     paged_attention_pallas,
     paged_attention_reference,
 )
-from repro.kvcache import pages_for
+from repro.kvcache import pages_for, paged_write
 from repro.launch.serve import BatchedServer, Request
 from repro.models import build_model
 from repro.models.attention import attention_block, init_attention
@@ -492,9 +496,10 @@ def test_paged_attention_kernel_matches_reference():
     pt = jnp.asarray(rng.permutation(p_total)[: b * n_pages]
                      .reshape(b, n_pages).astype(np.int32))
     lens = jnp.asarray([17, 1, 31], jnp.int32)
+    pool = jnp.stack([kp, vp])[None]  # one layer's planes as a whole pool
     for kw in ({}, {"window": 9}, {"chunk": 16}):
         ref = paged_attention_reference(q, kp, vp, pt, lens, **kw)
-        out = paged_attention_pallas(q, kp, vp, pt, lens, interpret=True,
+        out = paged_attention_pallas(q, pool, 0, pt, lens, interpret=True,
                                      **kw)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5, err_msg=str(kw))
@@ -510,21 +515,22 @@ def test_attention_block_kernel_dispatch_glue(monkeypatch):
     cfg, _, _ = _tiny_model()
     p = init_attention(jax.random.PRNGKey(5), cfg, jnp.float32)
     rng = np.random.default_rng(6)
-    b, smax, page, pool = 2, 16, 4, 10
+    b, smax, page, pool, n_layers, layer = 2, 16, 4, 10, 3, 1
     n_pages = smax // page
     x = jnp.asarray(rng.normal(size=(b, 1, cfg.d_model)).astype(np.float32))
     lens = jnp.asarray([7, 3], jnp.int32)
     pos = lens[:, None]
     pages = jnp.asarray(rng.normal(
-        size=(2, pool, page, cfg.n_kv_heads, cfg.hd)).astype(np.float32))
+        size=(n_layers, 2, pool, page, cfg.n_kv_heads, cfg.hd)
+    ).astype(np.float32))
     table = jnp.asarray(rng.permutation(pool)[: b * n_pages]
                         .reshape(b, n_pages).astype(np.int32))
 
     def paged(window=0):
         return attention_block(
-            p, cfg, x, pos, kv_pages=pages, page_table=table,
-            cache_len=lens, seq_lens=jnp.asarray([1, 1], jnp.int32),
-            layer_window=window,
+            p, cfg, x, pos, kv_pages=pages, kv_layer=layer,
+            page_table=table, cache_len=lens,
+            seq_lens=jnp.asarray([1, 1], jnp.int32), layer_window=window,
         )
 
     out_ref, cache_ref = paged()
@@ -543,6 +549,9 @@ def test_attention_block_kernel_dispatch_glue(monkeypatch):
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_ref),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_array_equal(np.asarray(cache_k), np.asarray(cache_ref))
+    others = [i for i in range(n_layers) if i != layer]
+    np.testing.assert_array_equal(np.asarray(cache_k)[others],
+                                  np.asarray(pages)[others])
     out_w, _ = paged(window=4)  # window plumb-through, still via kernel
     assert calls["n"] == 2
     monkeypatch.setattr(attn_mod, "_use_paged_kernel", lambda: False)
@@ -561,10 +570,127 @@ def test_paged_attention_kernel_empty_row_exact_zeros():
     vp = jnp.asarray(rng.normal(size=(p_total, page, kvh, hd)).astype(np.float32))
     pt = jnp.zeros((b, n_pages), jnp.int32)
     lens = jnp.asarray([0, 5], jnp.int32)
-    out = paged_attention_pallas(q, kp, vp, pt, lens, interpret=True)
+    out = paged_attention_pallas(q, jnp.stack([kp, vp])[None], 0, pt, lens,
+                                 interpret=True)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
     assert np.abs(np.asarray(out[1])).max() > 0
+
+
+# ---------------------------------------------------------------------------
+# The pool of every layer, read and written by layer index
+# ---------------------------------------------------------------------------
+
+N_LAYERS = 3
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_paged_kernel_reads_one_layer_of_the_whole_pool(layer):
+    """The kernel (interpret mode), handed every layer's pool and a traced
+    layer index, equals the reference over that layer's K and V planes
+    alone — plain, windowed and chunked, with an empty row exactly zero."""
+    rng = np.random.default_rng(10 + layer)
+    b, kvh, g, hd, p_total, page, n_pages = 3, 2, 4, 32, 16, 8, 4
+    q = jnp.asarray(rng.normal(size=(b, kvh, g, hd)).astype(np.float32))
+    pool = jnp.asarray(rng.normal(
+        size=(N_LAYERS, 2, p_total, page, kvh, hd)).astype(np.float32))
+    pt = jnp.asarray(rng.permutation(p_total)[: b * n_pages]
+                     .reshape(b, n_pages).astype(np.int32))
+    lens = jnp.asarray([17, 0, 31], jnp.int32)
+    for kw in ({}, {"window": 9}, {"chunk": 16}):
+        ref = paged_attention_reference(q, pool[layer, 0], pool[layer, 1],
+                                        pt, lens, **kw)
+        out = paged_attention_pallas(q, pool, jnp.int32(layer), pt, lens,
+                                     interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5, err_msg=str(kw))
+        np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
+
+
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_paged_write_touches_only_its_layer(layer):
+    """A scatter into layer ``layer`` of the whole pool writes exactly that
+    layer's K and V slots of each valid position (a frozen row and the
+    positions past a row's table are dropped) and leaves every other
+    layer bit-equal."""
+    rng = np.random.default_rng(20 + layer)
+    p_total, page, kvh, hd, b, s, n_pages = 12, 4, 2, 8, 3, 5, 3
+    pool = rng.normal(size=(N_LAYERS, 2, p_total, page, kvh, hd)
+                      ).astype(np.float32)
+    k = rng.normal(size=(b, s, kvh, hd)).astype(np.float32)
+    v = rng.normal(size=(b, s, kvh, hd)).astype(np.float32)
+    table = rng.permutation(p_total)[: b * n_pages].reshape(b, n_pages)
+    starts, seq_lens = [0, 9, 4], [5, 5, 0]  # row 1 runs off its table
+    out = np.asarray(paged_write(
+        jnp.asarray(pool), jnp.int32(layer), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(table, jnp.int32), jnp.asarray(starts, jnp.int32),
+        jnp.asarray(seq_lens, jnp.int32)))
+    want = pool.copy()
+    for r in range(b):
+        for j in range(seq_lens[r]):
+            t = starts[r] + j
+            if t < n_pages * page:
+                phys = table[r, t // page]
+                want[layer, 0, phys, t % page] = k[r, j]
+                want[layer, 1, phys, t % page] = v[r, j]
+    np.testing.assert_array_equal(out, want)
+    others = [i for i in range(N_LAYERS) if i != layer]
+    np.testing.assert_array_equal(out[others], pool[others])
+
+
+def _cow_requests(cfg, page):
+    """Two copies of a page-aligned prompt (the second is a full prefix
+    hit whose one-token rollback copy-on-writes a shared page) and a
+    third that shares its first page."""
+    rng = np.random.default_rng(23)
+    prompt = rng.integers(0, cfg.vocab_size, 2 * page, dtype=np.int32)
+    tail = rng.integers(0, cfg.vocab_size, page + 3, dtype=np.int32)
+    return [Request(0, prompt.copy(), 4), Request(1, prompt.copy(), 4),
+            Request(2, np.concatenate([prompt[:page], tail]), 4)]
+
+
+@pytest.mark.parametrize("arch,n_layers,scenario", [
+    ("llama32-1b", 2, "prefix_cow"),
+    ("llama32-1b", 2, "preempt_spill"),
+    ("zamba2-1.2b", 4, "preempt_spill"),
+])
+def test_donated_server_streams_match_isolated(arch, n_layers, scenario,
+                                               tmp_path):
+    """The decode and prefill programs take the cache donated, and the
+    host edits between rounds (prefix hits, copy-on-write page copies,
+    preemption, spill and page reload) keep working on the pool they hand
+    back: every stream equals its request's isolated decode, and every
+    paged round's output pool is the buffer it was given."""
+    from repro.serve import SpillStore
+
+    cfg, model, params = _tiny_model(arch, n_layers=n_layers)
+    max_len = 48
+    if scenario == "prefix_cow":
+        page = 8
+        reqs = _cow_requests(cfg, page)
+        kw = dict(batch_slots=2, paged=True, page_size=page, num_pages=12,
+                  prefix_cache=True)
+    else:
+        reqs = _requests(cfg, [10, 14], 16)
+        kw = dict(batch_slots=2, paged=True, page_size=4, num_pages=10,
+                  page_growth=True, inject="oop@tick2,oop@tick6",
+                  spill_store=SpillStore(tmp_path / "spill"),
+                  spill_threshold=0)
+    server = BatchedServer(model, params, max_len=max_len, **kw)
+    stats = server.run(reqs)
+    for r in reqs:
+        want = _isolated_decode(model, params, r.prompt, r.max_new, max_len)
+        assert r.out == want, (r.rid, r.out, want)
+    if scenario == "prefix_cow":
+        assert stats["prefix"]["hits"] >= 2, stats["prefix"]
+        assert stats["pages"]["cow_copies"] >= 1, stats["pages"]
+    else:
+        res = stats["resilience"]
+        assert res["preemptions"] >= 1 and res["spills"] >= 1, res
+        assert res["spill_restores"] == res["spills"], res
+    kv = stats["kv"]
+    assert kv["pool_rounds"] == stats["decode_steps"] + stats["prefill_waves"]
+    assert kv["pool_in_place"] == kv["pool_rounds"] > 0, kv
 
 
 # ---------------------------------------------------------------------------
@@ -596,7 +722,7 @@ def test_empty_row_attention_is_exact_zero_not_garbage_key():
     assert np.abs(out[1]).max() > 0
     # paged layout, same contract
     pages = jnp.asarray(np.random.default_rng(4).normal(
-        size=(2, 6, 4, cfg.n_kv_heads, cfg.hd)).astype(np.float32))
+        size=(1, 2, 6, 4, cfg.n_kv_heads, cfg.hd)).astype(np.float32))
     table = jnp.asarray([[5, 2, 0, 1], [3, 4, 1, 0]], jnp.int32)
     out_p, _ = attention_block(
         p, cfg, x, pos, kv_pages=pages, page_table=table,

@@ -4,12 +4,16 @@ The kernels are compiled (not interpreted) for a *described* v5e:2x2 chip at
 llama32-1b widths, through the same ops wrappers serving calls: block
 choice, padding and grouped-launch starts included. Nothing runs; a kernel
 the chip's compiler refuses (unaligned blocks, scoped VMEM, ops Mosaic
-cannot lower) fails here instead of on the chip.
+cannot lower) fails here instead of on the chip. The serving decode and
+prefill programs are compiled too, at qwen3-0.6b's attention widths, to
+pin that they update the paged KV pool in place.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
+import dataclasses
+import math
 import re
 
 import jax
@@ -140,13 +144,14 @@ def test_planes_and_linear_kernels_compile(compiled):
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_paged_attention_compiles(compiled, dtype):
-    b, kv, g, hd, page, n_row = 8, 8, 4, 64, 16, 35
+    b, kv, g, hd, page, n_row, layers = 8, 8, 4, 64, 16, 35, 3
     pool = b * n_row
     s = compiled
     args = (
         jax.ShapeDtypeStruct((b, kv, g, hd), dtype, sharding=s),
-        jax.ShapeDtypeStruct((pool, page, kv, hd), dtype, sharding=s),
-        jax.ShapeDtypeStruct((pool, page, kv, hd), dtype, sharding=s),
+        jax.ShapeDtypeStruct((layers, 2, pool, page, kv, hd), dtype,
+                             sharding=s),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=s),
         jax.ShapeDtypeStruct((b, n_row), jnp.int32, sharding=s),
         jax.ShapeDtypeStruct((b,), jnp.int32, sharding=s),
     )
@@ -154,3 +159,84 @@ def test_paged_attention_compiles(compiled, dtype):
     names = _kernel_names(_compile(ops.paged_attention, *args))
     assert names and all(n.startswith("paged_attention")
                          for n in names), names
+
+
+# the serving programs: qwen3-0.6b's attention widths at 2 layers, with a
+# pool too large for the compiler to stage whole in fast memory (at a few
+# MiB it copies the pool into VMEM, which says nothing about HBM traffic)
+POOL_PAGES, PAGE, SLOTS, MAX_LEN, BUCKET = 2048, 16, 16, 256, 64
+_VIEWS = {"parameter", "get-tuple-element", "bitcast"}
+_OP = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                 r"([\w\-]+)\((.*)$", re.M)
+
+
+def _pool_producers(text, pool):
+    """(name, opcode, op_name) of every instruction whose result holds a
+    whole pool's, one layer's or one K/V plane's elements of the pool's
+    dtype; returned by kind."""
+    whole = math.prod(pool.shape)
+    layer, plane = whole // pool.shape[0], whole // (2 * pool.shape[0])
+    kind = {whole: "pool", layer: "layer", plane: "plane"}
+    found = {"pool": [], "layer": [], "plane": []}
+    for m in _OP.finditer(text):
+        name, dtype, dims, opcode, rest = m.groups()
+        n = math.prod(int(d) for d in dims.split(",")) if dims else 1
+        if dtype == pool.dtype.name.replace("bfloat16", "bf16") and n in kind:
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            found[kind[n]].append(
+                (name, opcode, op_name.group(1) if op_name else ""))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_programs_update_the_pool_in_place(compiled, monkeypatch,
+                                                   program):
+    """``BatchedServer``'s decode and prefill programs, as it jits them,
+    update the paged pool in place: no op yields a layer's pool or a K/V
+    plane, only views of the carried pool and the scatter of each layer's
+    new K/V into it. Both take the pool donated and hand it back in the
+    same buffer, with no second pool."""
+    import repro.models.attention as attn_mod
+    from repro.configs import get_config
+    from repro.launch.serve import BatchedServer
+    from repro.models import build_model
+
+    monkeypatch.setattr(attn_mod, "_use_paged_kernel", lambda: True)
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), n_layers=2,
+                              vocab_size=4096)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # the server holds a small pool; its programs compile for a large one
+    server = BatchedServer(model, params, batch_slots=SLOTS,
+                           max_len=MAX_LEN, paged=True, page_size=PAGE,
+                           num_pages=64)
+    s = compiled
+    cache = _on(s, server.cache)
+    pool = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 2, POOL_PAGES, PAGE, cfg.n_kv_heads, cfg.hd),
+        cache["pages"].dtype, sharding=s)
+    cache["pages"] = pool
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=s)
+
+    flags = jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=s)
+    if program == "decode":
+        lowered = server._decode.lower(_on(s, params), i32(SLOTS, 1), cache,
+                                       active=flags)
+    else:
+        lowered = server._prefill.lower(_on(s, params), i32(SLOTS, BUCKET),
+                                        i32(SLOTS), flags, i32(SLOTS), cache)
+    exe = lowered.compile()
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes < pool_bytes, mem.temp_size_in_bytes
+    found = _pool_producers(exe.as_text(), pool)
+    assert not found["layer"] and not found["plane"], found
+    copies = [f for f in found["pool"] if f[1] not in _VIEWS
+              and not (f[1] in ("fusion", "scatter")
+                       and f[2].rsplit("/", 1)[-1] == "scatter")]
+    assert not copies, copies
+    # the carried pool is there, updated by a scatter
+    assert any(f[1] == "scatter" for f in found["pool"]), found["pool"]
